@@ -308,8 +308,8 @@ def main(argv=None) -> int:
     ap.add_argument("--tune", action="store_true",
                     help="run the block autotuner over the benchmark "
                          "shape classes first (winners land in the "
-                         "on-disk cache; records flip to blocks_source="
-                         "'tuned')")
+                         "table $REPRO_AUTOTUNE_CACHE names; records flip "
+                         "to blocks_source='tuned')")
     args = ap.parse_args(argv)
     if args.tune:
         autotune.tune_standard_shapes(verbose=True)

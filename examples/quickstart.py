@@ -12,8 +12,9 @@
    calibration, integer export, and deployed serving.
 5. Backend selection: the calibrate -> export -> kernel-serving flow.
    Deployed GEMMs dispatch through the ``repro.exec`` registry —
-   ``oracle`` (jnp reference), ``pallas`` (the real kernel; interpret
-   mode on CPU), ``auto`` (kernel on TPU, oracle elsewhere) — and greedy
+   ``oracle`` (jnp reference), ``pallas`` (the real kernel;
+   ``PallasBackend(interpret=True)`` on CPU), ``auto`` (kernel on TPU,
+   oracle elsewhere) — and greedy
    decodes are token-for-token identical across backends.
 6. Search a policy: ``repro.search`` walks an architecture's actual GEMM
    inventory (one layer namespace shared with the quantizer), scores
@@ -30,11 +31,10 @@ exponent layout) per shape class through ``repro.kernels.autotune`` —
 decode (M=1) takes a single-row fast-path kernel, prefill gets large
 MXU-aligned tiles, MoE expert banks run one fused grid over all experts.
 The default is a static heuristic (nothing is ever timed at trace time);
-``PYTHONPATH=src python -m repro.kernels.autotune`` measures the real
-candidates on this host and caches winners in
-``~/.cache/repro-apsq/autotune-v1.json`` (override with
-``$REPRO_AUTOTUNE_CACHE``), after which every kernel launch — including
-the serving engines below — picks them up automatically.
+``PYTHONPATH=src python -m repro.kernels.autotune --cache t.json``
+measures the real candidates on this host and writes the winners to
+``t.json``; with ``REPRO_AUTOTUNE_CACHE=t.json`` set, every kernel
+launch — including the serving engines below — picks them up.
 ``python -m repro.kernels.autotune --show`` prints the resolved table.
 """
 import jax
@@ -89,6 +89,7 @@ print(f"\nBERT FFN layer, WS dataflow: INT32-PSUM {e32['total']:.2e} J "
 from repro.models.config import ModelConfig
 from repro.models.model import forward, init_lm
 from repro.quant import QuantPolicy, calibrate_model, export_quantized
+from repro.exec import PallasBackend
 from repro.serving import Request, ServingEngine
 
 policy = QuantPolicy.of(
@@ -125,14 +126,15 @@ print(f"integer-deployed engine decoded: {done[0].out}")
 # --- 5. backend selection: serve the calibrated model through the kernel ----
 # ``from_exported`` exports and serves in one call; ``backend=`` picks the
 # executor.  "auto" (default) runs the Pallas kernel on TPU and the
-# bit-identical jnp oracle elsewhere; pinning "pallas" on CPU exercises
-# the kernel in interpret mode — same integers, token-for-token.
+# bit-identical jnp oracle elsewhere; PallasBackend(interpret=True) runs
+# the kernel in the Pallas interpreter on CPU — same integers,
+# token-for-token.
 prompt = np.arange(6) % cfg.vocab
 decodes = {}
-for backend in ("oracle", "pallas"):
+for backend in ("oracle", PallasBackend(interpret=True)):
     eng = ServingEngine.from_exported(params, cfg, max_batch=1, cache_len=64,
                                       prefill_chunk=8, backend=backend)
-    decodes[backend] = eng.run([Request(uid=1, tokens=prompt,
+    decodes[eng.backend.name] = eng.run([Request(uid=1, tokens=prompt,
                                         max_new_tokens=6)])[0].out
 print(f"\nkernel-served decode ({'==' if decodes['oracle'] == decodes['pallas'] else '!='} oracle): "
       f"{decodes['pallas']}")

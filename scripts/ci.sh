@@ -57,7 +57,7 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 python -m pip install --quiet -r requirements-dev.txt
-python -m pip install --quiet "jax>=0.4.30" numpy 2>/dev/null || true
+python -m pip install --quiet "jax==0.9.0" numpy 2>/dev/null || true
 
 if [[ "${1:-}" == "kernel-backend" ]]; then
     shift

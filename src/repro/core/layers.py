@@ -362,8 +362,9 @@ def deployed_dense(x: jax.Array, dq: DeployedQuantState, *,
 
     The actual integer GEMM is dispatched through the ``repro.exec``
     backend registry: ``oracle`` (pure jnp, runs under jit/scan/vmap),
-    ``pallas`` (the real kernel; interpret mode off-TPU), or ``auto``
-    (default: pallas on TPU, oracle elsewhere) — all bit-identical.
+    ``pallas`` (the compiled kernel; ``PallasBackend(interpret=True)`` on
+    CPU), or ``auto`` (default: pallas on TPU, oracle elsewhere) — all
+    bit-identical.
     """
     from repro.exec import execute_gemm  # lazy: exec imports kernels
 

@@ -5,7 +5,7 @@ Three layers, one per training/serving concern:
 ``sharding``
     maps the logical axis names used by every ``*_specs`` tree in
     ``repro.models`` onto concrete mesh axes (with divisibility fallbacks
-    and no-axis-reuse), and hosts the version-portable ``shard_map``
+    and no-axis-reuse), and hosts the ``shard_map``
     wrapper every manual-collective region in the repo goes through.
 
 ``tp``

@@ -28,28 +28,16 @@ from jax.sharding import PartitionSpec as P
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
               check_vma: bool = False):
-    """Version-portable shard_map.
+    """``jax.shard_map`` with this repo's defaults.
 
-    ``jax.shard_map`` (with ``check_vma``/``axis_names``) only exists on
-    newer jax; older releases ship ``jax.experimental.shard_map.shard_map``
-    (with ``check_rep``, and partial-manual expressed as the complementary
-    ``auto`` set).  Every shard_map call site in the repo goes through here
-    so multi-pod paths work on both.
-
+    Every shard_map call site in the repo goes through here.
     ``axis_names``: the axes the body is *manual* over (e.g. {"pod"} for
     DCN gradient compression, leaving "data"/"model" to GSPMD).  None
     means manual over every mesh axis.
     """
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    kw = {}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma, **kw)
+    kw = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 # logical name -> ordered candidate mesh-axis groups (each a tuple of axes)
 DEFAULT_RULES = {

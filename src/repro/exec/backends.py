@@ -10,12 +10,15 @@ of backends behind one entry point, ``execute_gemm``:
     differentiable-adjacent; the reference all other backends must match
     bit-for-bit.
   * ``pallas`` — the real ``kernels/apsq_matmul`` Pallas TPU kernel
-    (INT8 PSUM banks in VMEM).  On CPU it runs in interpret mode, so the
-    same code path is CI-testable; on TPU it is the hardware datapath the
-    paper's energy claims (§V) ride on.
+    (INT8 PSUM banks in VMEM), compiled for the chip: the hardware
+    datapath the paper's energy claims (§V) ride on.  It never falls
+    back: off the chip it fails.  ``PallasBackend(interpret=True)`` runs
+    the same kernels in the Pallas interpreter, which is how the CPU
+    tests exercise them.
   * ``auto``   — ``pallas`` when the default JAX backend is TPU, else
-    ``oracle``.  The serving default: decode hits the kernel on hardware
-    and stays bit-identical on CPU.
+    ``oracle``.  A convenience for CPU tests and examples; the chip path
+    names ``pallas`` explicitly so that a missing chip cannot pass
+    unnoticed.
 
 Every projection GEMM in the model zoo dispatches here when its params are
 deployed (``models.common.dense`` -> ``core.deployed_dense`` ->
@@ -110,10 +113,10 @@ class OracleBackend(ExecBackend):
 
 
 class PallasBackend(ExecBackend):
-    """The real Pallas kernels (interpret mode off-TPU, hardware on TPU).
+    """The real Pallas kernels, compiled for the TPU.
 
-    ``interpret=None`` auto-selects (interpret unless running on TPU);
-    pass ``interpret=True`` to force the interpreter (CI determinism).
+    ``interpret=True`` runs them in the Pallas interpreter instead (the
+    CPU tests); the default never does, so off the chip it fails.
 
     Launch geometry comes from ``repro.kernels.autotune``: every GEMM
     resolves (block_m, block_n, exponent layout) per shape class —
@@ -125,7 +128,7 @@ class PallasBackend(ExecBackend):
 
     name = "pallas"
 
-    def __init__(self, interpret: bool | None = None,
+    def __init__(self, interpret: bool = False,
                  block_overrides: dict | None = None):
         self.interpret = interpret
         self.block_overrides = dict(block_overrides or {})
@@ -187,7 +190,11 @@ class PallasBackend(ExecBackend):
 
 
 class AutoBackend(ExecBackend):
-    """``pallas`` on TPU, ``oracle`` elsewhere (resolved at trace time)."""
+    """``pallas`` on TPU, ``oracle`` elsewhere (resolved at trace time).
+
+    A convenience for CPU tests and examples, not the chip path: code that
+    must run the kernels (``chip_smoke.py``, ``launch/serve.py --backend
+    pallas``) names ``pallas``, which fails instead of falling back."""
 
     name = "auto"
 
@@ -317,7 +324,8 @@ def get_backend(backend=None) -> ExecBackend:
 
 def quantize_activations(x2d: jax.Array, ax_exp: jax.Array,
                          a_bits: int = 8) -> jax.Array:
-    """Float activations [M, K] -> INT8 codes at the PO2 scale 2^ax_exp."""
+    """Float activations [M, K] -> INT8 codes at the PO2 scale 2^ax_exp
+    (elementwise: any ``ax_exp`` that broadcasts against ``x2d``)."""
     qn, qp = qrange(a_bits, True)
     xf = x2d.astype(jnp.float32)
     return jnp.clip(jnp.round(xf * jnp.exp2(-ax_exp.astype(jnp.float32))),
@@ -377,7 +385,7 @@ def execute_kv_attention(q: jax.Array, k_codes: jax.Array,
     cache prefix.  Returns output matching q's rank, in q's dtype.  This
     is the second op family beside ``execute_gemm``: the ``oracle``
     backend runs the shape-polymorphic jnp reference, the ``pallas``
-    backend the flash-decode TPU kernel (interpret off-TPU); chunked
+    backend the flash-decode TPU kernel; chunked
     launches resolve their KV tile via the ``prefill_attn`` autotune
     shape class.
     """
@@ -392,9 +400,11 @@ def execute_kv_attention(q: jax.Array, k_codes: jax.Array,
 
 
 def backend_parity_check(dq: DeployedQuantState, x: jax.Array, *,
-                         backends=("oracle", "pallas"), reps: int = 1,
-                         warmup: int = 1):
+                         backends=None, reps: int = 1, warmup: int = 1):
     """Run one deployed GEMM through several backends, side by side.
+
+    ``backends`` defaults to the oracle and the interpreted Pallas kernel
+    (the CPU parity check).
 
     Returns ``(outs, times_us, bit_equal)``: per-backend outputs,
     per-backend wall-clock (jitted, post-warmup, microseconds), and
@@ -406,6 +416,8 @@ def backend_parity_check(dq: DeployedQuantState, x: jax.Array, *,
 
     import numpy as np
 
+    if backends is None:
+        backends = ("oracle", PallasBackend(interpret=True))
     outs, times = {}, {}
     for be in backends:
         resolved = get_backend(be)
@@ -431,8 +443,9 @@ def execute_expert_gemm(dq: DeployedQuantState, x: jax.Array, *,
     [E, K, N], ax_exp [E], aw_exp [E, ...], psum_exps [E, n_p, ...] — the
     per-expert exponent banks emitted by ``export_quantized``).  All E
     experts execute as ONE backend op: activations quantize per expert
-    (vmapped PO2 shifts), the backend's ``int_expert_gemm`` runs the
-    stacked integer GEMM — a single fused ``pallas_call`` whose grid
+    (each expert's PO2 shift broadcast over its rows), the backend's
+    ``int_expert_gemm`` runs the stacked integer GEMM — a single fused
+    ``pallas_call`` whose grid
     carries the expert axis on the Pallas backend, E oracle calls on the
     reference backend — and the INT32 outputs rescale per expert by
     ``2^(ax_exp[e] + aw_exp[e])``.  Bit-identical to slicing expert ``e``
@@ -443,10 +456,8 @@ def execute_expert_gemm(dq: DeployedQuantState, x: jax.Array, *,
     n_exp = int(dq.w_codes.shape[0])
     k = int(dq.w_codes.shape[-2])
     out_shape = x.shape[:-1] + dq.out_dims
-    xc = jax.vmap(
-        lambda xe, ae: quantize_activations(xe.reshape(-1, k), ae,
-                                            spec.a_bits)
-    )(x, dq.ax_exp)
+    xc = quantize_activations(x.reshape(n_exp, -1, k),
+                              dq.ax_exp.reshape(n_exp, 1, 1), spec.a_bits)
     gs = 1
     if dq.psum_exps is not None:
         n_p = int(dq.psum_exps.shape[1])

@@ -146,9 +146,7 @@ def _apsq_kernel(exp_ref, x_ref, w_ref, out_ref, banks_ref, *, n_p: int,
 
     @pl.when((k > 0) & (k % gs != 0) & (k < last))
     def _tail():  # plain PSQ on a tail tile
-        code = _quantize(prod, exp(k))
-        pl.store(banks_ref, (pl.dslice(k % gs, 1), slice(None), slice(None)),
-                 code[None])
+        banks_ref[pl.ds(k % gs, 1)] = _quantize(prod, exp(k))[None]
 
     @pl.when(k == last)
     def _final():
@@ -189,21 +187,15 @@ def _baseline_kernel(x_ref, w_ref, out_ref, acc_ref, *, n_p: int):
         out_ref[...] = acc_ref[...] if n_p > 1 else prod
 
 
-def _make_params(sem: tuple):
-    try:
-        return pltpu.CompilerParams(dimension_semantics=sem)
-    except AttributeError:  # older jax
-        return pltpu.TPUCompilerParams(dimension_semantics=sem)
-
-
 def _compiler_params(n_dims: int):
     """dimension_semantics: M/N parallel, K sequential (banks carry state)."""
-    return _make_params(("parallel",) * (n_dims - 1) + ("arbitrary",))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * (n_dims - 1) + ("arbitrary",))
 
 
 def _parallel_params(n_dims: int):
     """All-parallel semantics (no cross-step state — the m=1 fast path)."""
-    return _make_params(("parallel",) * n_dims)
+    return pltpu.CompilerParams(dimension_semantics=("parallel",) * n_dims)
 
 
 @functools.partial(
@@ -375,9 +367,7 @@ def _apsq_expert_kernel(exp_ref, x_ref, w_ref, out_ref, banks_ref, *,
 
     @pl.when((k > 0) & (k % gs != 0) & (k < last))
     def _tail():
-        code = _quantize(prod, exp(k))
-        pl.store(banks_ref, (pl.dslice(k % gs, 1), slice(None), slice(None)),
-                 code[None])
+        banks_ref[pl.ds(k % gs, 1)] = _quantize(prod, exp(k))[None]
 
     @pl.when(k == last)
     def _final():

@@ -1,7 +1,9 @@
 """Jit'd public wrappers around the APSQ Pallas kernels.
 
-Handles padding to block multiples, interpret-mode fallback on CPU, operand
-quantization from float, and rescaling of the integer result back to float.
+Handles padding to block multiples, operand quantization from float, and
+rescaling of the integer result back to float.  The kernels compile for the
+TPU (Mosaic); ``interpret=True`` runs them in the Pallas interpreter, which
+is how the CPU tests exercise them.
 
 Block sizes: every entry point takes ``block_m``/``block_n`` (and, where
 2-D exponents are in play, ``exp_layout``).  Left as ``None`` they resolve
@@ -29,8 +31,8 @@ from .kernel import (
 )
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+# Mosaic tiles the minor dimension of a block in 128-lane vectors.
+LANE = 128
 
 
 def _pad_to(x: jax.Array, m0: int, m1: int) -> jax.Array:
@@ -43,6 +45,37 @@ def _pad_to(x: jax.Array, m0: int, m1: int) -> jax.Array:
 
 def _round_up(x: int, mult: int) -> int:
     return -(-x // mult) * mult
+
+
+def pad_psum_tiles(x_codes: jax.Array, w_codes: jax.Array, n_p: int):
+    """Zero-pad K so every PSUM tile's K slice is a whole number of lanes.
+
+    ``x_codes`` [..., M, K], ``w_codes`` [..., K, N].  K first gets the
+    ragged remainder group of ``ref.pad_ragged_k`` (tile width
+    ``ceil(K / n_p)``); then each tile's slice is padded on its own to a
+    multiple of ``LANE``, which the generic and expert grids need for a
+    legal ``block_k`` on the chip.  Zero codes add nothing, and every K
+    index stays in its own tile, so the result is bit-identical to the
+    unpadded Algorithm 1.  (Padding K as a whole would move tile
+    boundaries.)  n_p == 1 needs no lane padding: its block is all of K.
+    """
+    k = x_codes.shape[-1]
+    kt = -(-k // n_p)
+    ktp = kt if n_p == 1 else _round_up(kt, LANE)
+    if ktp * n_p == k:
+        return x_codes, w_codes
+    lead_x, lead_w = x_codes.shape[:-1], w_codes.shape[:-2]
+    n = w_codes.shape[-1]
+    pad_x = [(0, 0)] * (x_codes.ndim - 1)
+    pad_w = [(0, 0)] * (w_codes.ndim - 2)
+    x = jnp.pad(x_codes, pad_x + [(0, n_p * kt - k)])
+    w = jnp.pad(w_codes, pad_w + [(0, n_p * kt - k), (0, 0)])
+    x = jnp.pad(x.reshape(*lead_x, n_p, kt),
+                pad_x + [(0, 0), (0, ktp - kt)])
+    w = jnp.pad(w.reshape(*lead_w, n_p, kt, n),
+                pad_w + [(0, 0), (0, ktp - kt), (0, 0)])
+    return (x.reshape(*lead_x, n_p * ktp),
+            w.reshape(*lead_w, n_p * ktp, n))
 
 
 def _resolve_blocks(m, k, n, *, n_p, gs, block_m, block_n, exp_layout=None,
@@ -75,7 +108,7 @@ def apsq_matmul_int8(
     block_m: int | None = None,
     block_n: int | None = None,
     exp_layout: str | None = None,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """INT8 GEMM with Algorithm-1 PSUM handling; returns INT32 [M, N].
 
@@ -88,25 +121,25 @@ def apsq_matmul_int8(
     (``apsq_matmul_m1_kernel``: one grid row over N, the whole K
     reduction unrolled in-register) — bit-identical to the generic grid.
     """
-    if interpret is None:
-        interpret = _default_interpret()
     m, k = x_codes.shape
     n = w_codes.shape[1]
     n_p = int(exps.shape[0])
     bm, bn, layout = _resolve_blocks(m, k, n, n_p=n_p, gs=gs,
                                      block_m=block_m, block_n=block_n,
                                      exp_layout=exp_layout)
-    x_codes, w_codes = ref.pad_ragged_k(x_codes, w_codes, n_p)
     exps = exps.astype(jnp.int32)
     if exps.ndim == 2:  # pad the column axis alongside w (exponent 0 is id)
         exps = _pad_to(exps, 1, bn)
     if m == 1 and bm == 1:
+        # The fast path keeps all of K resident: only the ragged group.
+        x_codes, w_codes = ref.pad_ragged_k(x_codes, w_codes, n_p)
         wp = _pad_to(w_codes, 1, bn)
         out = apsq_matmul_m1_kernel(
             x_codes, wp, exps, n_p=n_p, gs=int(gs), block_n=bn,
             interpret=interpret)
         return out[:, :n]
     bm = max(bm, 8)  # the generic grid pads rows to sublane multiples
+    x_codes, w_codes = pad_psum_tiles(x_codes, w_codes, n_p)
     xp = _pad_to(x_codes, bm, 1)
     wp = _pad_to(w_codes, 1, bn)
     out = apsq_matmul_kernel(
@@ -125,7 +158,7 @@ def apsq_expert_matmul_int8(
     gs: int,
     block_m: int | None = None,
     block_n: int | None = None,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Fused expert-bank GEMM: [E, M, K] @ [E, K, N] -> [E, M, N] INT32.
 
@@ -134,8 +167,6 @@ def apsq_expert_matmul_int8(
     (per-tensor) or [E, n_p, N] (per-channel).  Ragged ``K % n_p`` gets
     the same zero-contribution remainder group as the single-expert path.
     """
-    if interpret is None:
-        interpret = _default_interpret()
     n_e, m, k = x_codes.shape
     n = w_codes.shape[2]
     n_p = int(exps.shape[1])
@@ -143,10 +174,7 @@ def apsq_expert_matmul_int8(
                                 block_n=block_n, exp_layout="blocked",
                                 expert=True)
     bm = max(bm, min(8, _round_up(m, 8)))  # expert grid has no m=1 path
-    pad_k = (-k) % n_p
-    if pad_k:
-        x_codes = jnp.pad(x_codes, ((0, 0), (0, 0), (0, pad_k)))
-        w_codes = jnp.pad(w_codes, ((0, 0), (0, pad_k), (0, 0)))
+    x_codes, w_codes = pad_psum_tiles(x_codes, w_codes, n_p)
     pad_m = (-m) % bm
     pad_n = (-n) % bn
     if pad_m:
@@ -170,21 +198,16 @@ def baseline_expert_matmul_int8(
     n_p: int = 1,
     block_m: int | None = None,
     block_n: int | None = None,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Fused INT32-accumulator W8A8 expert GEMM; returns INT32 [E, M, N]."""
-    if interpret is None:
-        interpret = _default_interpret()
     n_e, m, k = x_codes.shape
     n = w_codes.shape[2]
     bm, bn, _ = _resolve_blocks(m, k, n, n_p=n_p, gs=1, block_m=block_m,
                                 block_n=block_n, exp_layout="blocked",
                                 expert=True)
     bm = max(bm, min(8, _round_up(m, 8)))
-    pad_k = (-k) % n_p
-    if pad_k:
-        x_codes = jnp.pad(x_codes, ((0, 0), (0, 0), (0, pad_k)))
-        w_codes = jnp.pad(w_codes, ((0, 0), (0, pad_k), (0, 0)))
+    x_codes, w_codes = pad_psum_tiles(x_codes, w_codes, n_p)
     pad_m, pad_n = (-m) % bm, (-n) % bn
     if pad_m:
         x_codes = jnp.pad(x_codes, ((0, 0), (0, pad_m), (0, 0)))
@@ -204,17 +227,15 @@ def baseline_matmul_int8(
     n_p: int,
     block_m: int | None = None,
     block_n: int | None = None,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """INT32-accumulator W8A8 GEMM baseline; returns INT32 [M, N]."""
-    if interpret is None:
-        interpret = _default_interpret()
     m, k = x_codes.shape
     n = w_codes.shape[1]
     bm, bn, _ = _resolve_blocks(m, k, n, n_p=n_p, gs=1, block_m=block_m,
                                 block_n=block_n, exp_layout="blocked")
     bm = max(bm, min(8, _round_up(m, 8)))  # no m=1 kernel for the baseline
-    x_codes, w_codes = ref.pad_ragged_k(x_codes, w_codes, n_p)
+    x_codes, w_codes = pad_psum_tiles(x_codes, w_codes, n_p)
     xp = _pad_to(x_codes, bm, 1)
     wp = _pad_to(w_codes, 1, bn)
     out = baseline_matmul_kernel(
@@ -243,7 +264,7 @@ def apsq_matmul_f32(
     aw: jax.Array | float,
     block_m: int | None = None,
     block_n: int | None = None,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Deployment-path float entry: quantize -> integer kernel -> rescale.
 

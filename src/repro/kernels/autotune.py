@@ -11,9 +11,10 @@ the kernel level.
 Three layers, fastest first:
 
   * ``get_block_config(m, k, n, ...)`` — the hot-path lookup every kernel
-    launch goes through.  Never times anything: it consults the on-disk
-    cache of tuned winners and falls back to the static heuristic, so
-    interpret-mode CI and trace-time resolution stay deterministic.
+    launch goes through.  Never times anything: it consults the table of
+    tuned winners named by ``$REPRO_AUTOTUNE_CACHE`` (none when unset)
+    and falls back to the static heuristic, so interpret-mode CI and
+    trace-time resolution stay deterministic.
   * ``heuristic_config`` — the static fallback: shape-class-aware tile
     sizes clamped to a VMEM budget.
   * ``tune`` / ``tune_standard_shapes`` — the measured search.  Runs each
@@ -38,14 +39,16 @@ Shape classes
 
 Cache
 -----
-``$REPRO_AUTOTUNE_CACHE`` or ``~/.cache/repro-apsq/autotune-v1.json``.
-The file is versioned (``CACHE_VERSION`` is part of the path) and keyed
-by jax backend, so a CPU-tuned table never leaks onto TPU.
+The file ``$REPRO_AUTOTUNE_CACHE`` names, or a ``path=`` argument.  With
+neither there is no table: every launch takes the heuristic, so block
+choices never depend on state outside the checkout.  The file is
+versioned and keyed by jax backend, so a CPU-tuned table never leaks
+onto TPU.
 
 CLI::
 
-    PYTHONPATH=src python -m repro.kernels.autotune            # tune all
-    PYTHONPATH=src python -m repro.kernels.autotune --show     # table
+    PYTHONPATH=src python -m repro.kernels.autotune --cache t.json  # tune
+    PYTHONPATH=src python -m repro.kernels.autotune --show          # table
 """
 from __future__ import annotations
 
@@ -204,12 +207,9 @@ def candidate_configs(cls: str, m: int, k: int, n: int, *, n_p: int,
 # Cache
 # ---------------------------------------------------------------------------
 
-def cache_path() -> str:
-    env = os.environ.get("REPRO_AUTOTUNE_CACHE")
-    if env:
-        return env
-    return os.path.join(os.path.expanduser("~"), ".cache", "repro-apsq",
-                        f"autotune-v{CACHE_VERSION}.json")
+def cache_path() -> str | None:
+    """The tuned table's file: ``$REPRO_AUTOTUNE_CACHE``, else None."""
+    return os.environ.get("REPRO_AUTOTUNE_CACHE") or None
 
 
 def cache_key(cls: str, n_p: int, gs: int, backend: str | None = None) -> str:
@@ -222,6 +222,8 @@ _CACHE_MEM: dict[str, dict] = {}
 
 def _load_cache(path: str | None = None, *, refresh: bool = False) -> dict:
     path = path or cache_path()
+    if path is None:
+        return {}
     if refresh or path not in _CACHE_MEM:
         try:
             with open(path) as f:
@@ -236,6 +238,9 @@ def _load_cache(path: str | None = None, *, refresh: bool = False) -> dict:
 
 def _store_cache(entries: dict, path: str | None = None) -> None:
     path = path or cache_path()
+    if path is None:
+        raise ValueError("no autotune table: pass path= or set "
+                         "REPRO_AUTOTUNE_CACHE")
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
     tmp = path + ".tmp"
     with open(tmp, "w") as f:
@@ -277,7 +282,7 @@ def get_block_config(m: int, k: int, n: int, *, n_p: int, gs: int,
 
 def _default_measure(cfg: BlockConfig, m: int, k: int, n: int, *, n_p: int,
                      gs: int, expert: bool, reps: int,
-                     interpret: bool | None) -> float:
+                     interpret: bool) -> float:
     """Wall-clock one config (jit + warmup + ``block_until_ready``), us."""
     import jax.numpy as jnp
 
@@ -313,7 +318,7 @@ def _default_measure(cfg: BlockConfig, m: int, k: int, n: int, *, n_p: int,
 
 def _default_measure_attn(cfg: BlockConfig, m: int, k: int, n: int, *,
                           n_p: int, gs: int, expert: bool, reps: int,
-                          interpret: bool | None) -> float:
+                          interpret: bool) -> float:
     """Wall-clock one chunked KV-attention launch: m = chunk query rows,
     k = head dim, n = KV sequence; ``cfg.block_n`` is the requested
     ``block_s`` KV tile (snapped to a divisor of S, as at serving time)."""
@@ -345,7 +350,7 @@ def _default_measure_attn(cfg: BlockConfig, m: int, k: int, n: int, *,
 def tune(m: int, k: int, n: int, *, n_p: int, gs: int,
          expert: bool = False, attn: bool = False, reps: int = 3,
          path: str | None = None,
-         interpret: bool | None = None, measure=None,
+         interpret: bool = False, measure=None,
          verbose=None) -> BlockConfig:
     """Measure every candidate for this shape's class and cache the winner.
 
@@ -394,7 +399,7 @@ STANDARD_SHAPES = (
 
 def tune_standard_shapes(*, n_p: int = 8, gs: int = 2, reps: int = 3,
                          path: str | None = None,
-                         interpret: bool | None = None, measure=None,
+                         interpret: bool = False, measure=None,
                          verbose=None) -> dict[str, BlockConfig]:
     """Tune every shape class at its representative shape; returns winners."""
     out = {}
@@ -431,8 +436,8 @@ def main(argv=None) -> int:
     ap.add_argument("--gs", type=int, default=2)
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--cache", default=None,
-                    help="cache file (default: REPRO_AUTOTUNE_CACHE or "
-                         "~/.cache/repro-apsq/)")
+                    help="table file to write (default: "
+                         "$REPRO_AUTOTUNE_CACHE; one of them is needed)")
     args = ap.parse_args(argv)
     if args.show:
         for cls, rec in resolved_table(n_p=args.n_p, gs=args.gs).items():

@@ -4,12 +4,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .kernel import int8_kv_attention_kernel
+from .kernel import int8_kv_attention_kernel, legal_block_s
 from .ref import quantize_kv_po2
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def int8_kv_attention(
@@ -21,20 +17,20 @@ def int8_kv_attention(
     length: jax.Array | int,
     *,
     block_s: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Attention over an INT8 cache, matching q's rank.
 
     3D q is the decode form (one row per batch); 4D q is a prefill chunk
     of C causal rows ending at cache position ``length - 1``.  Returns
-    [B, Hq, hd] / [B, C, Hq, hd] in q's dtype.
+    [B, Hq, hd] / [B, C, Hq, hd] in q's dtype.  ``block_s`` must divide
+    S; a size the chip cannot tile is snapped to ``legal_block_s``.
     """
-    if interpret is None:
-        interpret = _default_interpret()
     B, S = k_codes.shape[:2]
     block_s = min(block_s, S)
     if S % block_s:
         raise ValueError(f"S={S} not divisible by block_s={block_s}")
+    block_s = legal_block_s(S, block_s)
     length = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,))
     out = int8_kv_attention_kernel(
         q, k_codes, v_codes, k_exp.astype(jnp.int32),
@@ -44,7 +40,7 @@ def int8_kv_attention(
 
 
 def int8_kv_attention_f32(q, k, v, length, *, block_s: int = 512,
-                          interpret: bool | None = None):
+                          interpret: bool = False):
     """Float entry: quantize the cache (PO2) then run the kernel."""
     k_codes, k_exp = quantize_kv_po2(k)
     v_codes, v_exp = quantize_kv_po2(v)

@@ -64,13 +64,15 @@ def int8_kv_attention_ref(
     k = dequantize_kv_po2(k_codes, k_exp)
     v = dequantize_kv_po2(v_codes, v_exp)
     qf = q.reshape(B, C, Hkv, G, hd).astype(jnp.float32)
-    s = jnp.einsum("bchgd,bshd->bchgs", qf, k) * scale
+    # HIGHEST: full-f32 dots, as the kernel computes them on the chip.
+    hi = jax.lax.Precision.HIGHEST
+    s = jnp.einsum("bchgd,bshd->bchgs", qf, k, precision=hi) * scale
     limit = (jnp.reshape(jnp.asarray(length), (-1, 1)) - C + 1
              + jnp.arange(C)[None])                 # [B, C]
     valid = jnp.arange(S)[None, None] < limit[..., None]
     s = jnp.where(valid[:, :, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bchgs,bshd->bchgd", p, v)
+    out = jnp.einsum("bchgs,bshd->bchgd", p, v, precision=hi)
     out = out.reshape(B, C, Hq, hd).astype(q.dtype)
     return out[:, 0] if squeeze else out
 

@@ -9,18 +9,29 @@ state (the dry-run sets XLA_FLAGS before any jax initialization).
 The "pod" axis is the DCN (data-center network) dimension; "data" and
 "model" are ICI axes within one pod.  Gradient compression and ZeRO-1
 moment sharding target "pod" (see repro.dist).
+
+Every mesh here has ``Auto`` axis types: the repo's sharding rules are
+GSPMD annotations that the compiler propagates, not shardings carried in
+array types (``jax.make_mesh``'s default, ``Explicit``, would make every
+reshape and concatenate of a sharded array check divisibility).
 """
 from __future__ import annotations
 
 import math
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_smoke_mesh(shape=None, axes=("data", "model")):
@@ -45,4 +56,4 @@ def make_smoke_mesh(shape=None, axes=("data", "model")):
         raise ValueError(f"mesh {shape} needs {need} devices, "
                          f"have {len(devs)} (set XLA_FLAGS="
                          f"--xla_force_host_platform_device_count=N)")
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return _mesh(shape, axes, devices=devs[:need])

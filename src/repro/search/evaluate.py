@@ -81,7 +81,7 @@ def backend_parity_report(cfg: ModelConfig, m: int = 8) -> dict:
 
     Exports one calibrated [d_model, d_model] linear under the cfg's
     policy and runs it through ``repro.exec.backend_parity_check``
-    (pallas in interpret mode off-TPU) — the side-by-side parity +
+    (pallas in interpret mode) — the side-by-side parity +
     wall-clock the roofline table reports next to each quantized cell.
     """
     from repro.core import quant_params_init, calibrate_dense
@@ -225,7 +225,7 @@ def roundtrip_report(cfg: ModelConfig, policy, batch: dict,
     parity through ``ServingEngine`` pinned to each backend.
     """
     from repro.core import DeployedQuantState
-    from repro.exec import backend_parity_check
+    from repro.exec import PallasBackend, backend_parity_check
     from repro.models.model import init_lm
     from repro.quant.export import export_quantized
     from repro.quant.qat import calibrate_model
@@ -260,15 +260,16 @@ def roundtrip_report(cfg: ModelConfig, policy, batch: dict,
             "layer": dq.name, "bit_equal": bool(bit_equal),
             **{f"{n}_us": round(t, 1) for n, t in times.items()}}
 
-    # (b) greedy decode parity: oracle vs pallas, token for token
+    # (b) greedy decode parity: oracle vs interpreted pallas, token for
+    # token
     prompt = np.asarray(batch["tokens"])[0, :8].astype(np.int64)
     decodes = {}
-    for backend in ("oracle", "pallas"):
+    for backend in ("oracle", PallasBackend(interpret=True)):
         eng = ServingEngine(deploy, cfg_q, max_batch=1, cache_len=64,
                             prefill_chunk=8, backend=backend)
         done = eng.run([Request(uid=0, tokens=prompt,
                                 max_new_tokens=max_new_tokens)])
-        decodes[backend] = list(done[0].out)
+        decodes[eng.backend.name] = list(done[0].out)
     report["decode"] = decodes
     report["serving_parity"] = decodes["oracle"] == decodes["pallas"]
     report["ok"] = bool(report.get("serving_parity")

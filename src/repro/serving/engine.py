@@ -11,9 +11,10 @@ with per-(slot, kv-head) power-of-two scales, shared by all request
 slots through a page table.  Pages are allocated on demand and reclaimed
 on finish/eviction by the host-side ``repro.serving.scheduler``; the
 attention read path is the ``kv_attention`` exec op family, i.e. the
-``kernels/int8_kv_attention`` flash-decode Pallas kernel on TPU and its
-jnp oracle elsewhere.  Because a slot's running exponents depend only on
-its own tokens, greedy decodes are token-identical regardless of which
+``kernels/int8_kv_attention`` flash-decode Pallas kernel or its jnp
+oracle, whichever backend the engine names.  Because a slot's running
+exponents depend only on its own tokens, greedy decodes are
+token-identical regardless of which
 other requests share the pool — admission and eviction mid-decode never
 change anyone's output.
 
@@ -77,17 +78,19 @@ Integer serving (the calibrate -> export -> kernel-serving flow):
 
 ``from_exported`` exports every quantized linear to INT8 codes + PO2
 shift exponents and the engine executes them through the ``repro.exec``
-backend registry: ``backend="auto"`` (default) runs the real Pallas
-APSQ kernel on TPU and the bit-identical jnp oracle elsewhere;
-``backend="pallas"`` pins the kernel (interpret mode off-TPU — what CI
-runs); ``backend="oracle"`` pins the reference semantics.  Greedy
-decodes are token-for-token identical across backends.
+backend registry: ``backend="pallas"`` runs the compiled Pallas kernels
+(the chip path; it fails without a TPU), ``PallasBackend(interpret=True)``
+the same kernels in the Pallas interpreter (what the CPU tests run),
+``backend="oracle"`` the jnp reference semantics, and ``backend="auto"``
+(default) pallas on TPU, the oracle elsewhere — a convenience for CPU
+tests and examples.  Greedy decodes are token-for-token identical across
+backends.
 
 Pallas launch geometry resolves per shape class through the block
 autotuner (``repro.kernels.autotune``): decode steps (M=1) take the
 single-row fast path, prefill chunks get large tiles, and stacked MoE
 expert banks run as ONE fused grid over all experts.  Tuned winners in
-the on-disk cache apply automatically; pass
+the table ``$REPRO_AUTOTUNE_CACHE`` names apply automatically; pass
 ``backend=PallasBackend(block_overrides={"decode_m1": BlockConfig(1,
 512)})`` to pin blocks for a shape class explicitly.
 
@@ -195,10 +198,12 @@ class ServingEngine:
         self.temperature = temperature
         self.rng = jax.random.PRNGKey(seed)
         # Integer execution backend for deployed params (repro.exec):
-        # "auto" (default) serves the Pallas kernel on TPU and the jnp
-        # oracle elsewhere; "pallas"/"oracle" (or an ExecBackend instance,
-        # e.g. PallasBackend(interpret=True)) pin one explicitly.  Float /
-        # fake-quant params ignore it.
+        # "pallas" (compiled kernels, fails off TPU) or "oracle", or an
+        # ExecBackend instance such as PallasBackend(interpret=True) for
+        # the CPU tests.  "auto" (default) picks pallas on TPU and the
+        # oracle elsewhere: a convenience for CPU tests and examples, not
+        # the chip path, which names "pallas" so a missing chip cannot
+        # pass unnoticed.  Float / fake-quant params ignore it.
         self.backend = get_backend(backend)
 
         self.state = init_decode_state(cfg, max_batch, cache_len)
@@ -227,8 +232,9 @@ class ServingEngine:
         params (INT8 weight codes + PO2 shift exponents per layer, see
         ``repro.quant.export``) and run every projection GEMM through the
         ``kernels/apsq_matmul`` integer semantics inside decode.  The
-        ``backend=`` knob picks the executor: ``auto`` (kernel on TPU,
-        oracle elsewhere), ``pallas``, or ``oracle``."""
+        ``backend=`` knob picks the executor: ``pallas`` (compiled
+        kernels), ``oracle``, or ``auto`` (kernel on TPU, oracle
+        elsewhere; for CPU tests)."""
         from repro.quant.export import export_quantized
         deploy, _ = export_quantized(params, policy)
         return cls(deploy, cfg, **kw)

@@ -150,7 +150,7 @@ def paged_update_and_attend(cache: dict, q: jax.Array, k_new: jax.Array,
     Returns (out [B, Hq, hd], new_cache).  The attention itself runs
     through ``repro.exec.execute_kv_attention`` with ``block_s`` = the
     page size, so the serving read path is the registered op family
-    (oracle jnp reference off-TPU, Pallas flash-decode kernel on TPU).
+    (the jnp oracle or the Pallas flash-decode kernel, per ``backend``).
     """
     from repro.exec import execute_kv_attention
     pos = jnp.asarray(pos, jnp.int32)

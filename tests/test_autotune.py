@@ -8,7 +8,6 @@ the same config — cold (fresh process view of the on-disk table) or warm
 ``measure`` so tests never depend on wall-clock.
 """
 import json
-import os
 
 import pytest
 
@@ -174,9 +173,14 @@ def test_env_var_picks_cache_path(tmp_path, monkeypatch):
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", p)
     assert autotune.cache_path() == p
     monkeypatch.delenv("REPRO_AUTOTUNE_CACHE")
-    assert autotune.cache_path().endswith(
-        os.path.join("repro-apsq",
-                     f"autotune-v{autotune.CACHE_VERSION}.json"))
+    # Unset: no table at all (nothing outside the checkout is read), so
+    # every launch takes the heuristic and tuning needs an explicit path.
+    assert autotune.cache_path() is None
+    autotune.clear_memory_cache()
+    assert get_block_config(256, 1024, 512, n_p=8, gs=2).source \
+        == "heuristic"
+    with pytest.raises(ValueError, match="REPRO_AUTOTUNE_CACHE"):
+        tune(256, 1024, 512, n_p=8, gs=2, measure=_fake_measure)
 
 
 def test_resolved_table_covers_all_classes(cache_file, monkeypatch):

@@ -25,7 +25,7 @@ import pytest
 from repro.core import QuantConfig
 from repro.dist.tp import (GemmPlan, plan_gemm, shard_deployed,
                            wire_report)
-from repro.exec import ShardedBackend, get_backend
+from repro.exec import PallasBackend, ShardedBackend, get_backend
 from repro.kernels.apsq_matmul.ref import choose_exps
 from repro.launch.mesh import make_smoke_mesh
 from repro.models.config import ModelConfig
@@ -307,11 +307,12 @@ def test_engine_sharded_decode_matches_single_device(case):
         # The acceptance bar is parity with the single-device *pallas*
         # backend: pin oracle == pallas here, and run the sharded engine
         # with the pallas kernel as the per-shard inner once.
-        pal_outs, pal_state = _decode(p2, cfg, backend="pallas")
+        pal = PallasBackend(interpret=True)
+        pal_outs, pal_state = _decode(p2, cfg, backend=pal)
         assert pal_outs == ref_outs
         jax.tree.map(np.testing.assert_array_equal, ref_state, pal_state)
         outs, state = _decode(p2, cfg, mesh=make_smoke_mesh((1, 2)),
-                              backend="pallas")
+                              backend=pal)
         assert outs == ref_outs
         jax.tree.map(np.testing.assert_array_equal, ref_state, state)
 

@@ -3,7 +3,7 @@
 The acceptance bar for the backend subsystem: every deployed projection
 GEMM — dense linears (per-channel scales), MoE expert banks, the
 tied-embedding head — dispatches through ``repro.exec.execute_gemm``, and
-``ServingEngine.from_exported(backend="pallas")`` greedy-decodes
+``ServingEngine.from_exported`` with the Pallas backend greedy-decodes
 token-for-token identically to ``backend="oracle"``.
 """
 import jax
@@ -27,6 +27,9 @@ from repro.models.config import ModelConfig
 from repro.models.model import forward, init_lm
 from repro.quant import QuantPolicy, calibrate_model, export_quantized, \
     snap_params_po2
+
+# The Pallas kernels in the interpreter: the CPU form of ``pallas``.
+PALLAS = PallasBackend(interpret=True)
 
 
 def _cfg(**kw):
@@ -90,7 +93,7 @@ def test_execute_gemm_flattens_leading_dims():
     for shape in ((2, 4, 32), (3, 1, 32)):
         xb = jnp.broadcast_to(x[0], shape)
         y_o = execute_gemm(dq, xb, backend="oracle")
-        y_p = execute_gemm(dq, xb, backend="pallas")
+        y_p = execute_gemm(dq, xb, backend=PALLAS)
         assert y_o.shape == shape[:-1] + dq.out_dims
         np.testing.assert_array_equal(np.asarray(y_o), np.asarray(y_p))
 
@@ -103,17 +106,17 @@ def test_execute_gemm_w8a8_baseline_path():
         psum=QuantConfig.w8a8().psum)
     assert dq.psum_exps is None
     y_o = execute_gemm(dq, x, backend="oracle")
-    y_p = execute_gemm(dq, x, backend="pallas")
+    y_p = execute_gemm(dq, x, backend=PALLAS)
     np.testing.assert_array_equal(np.asarray(y_o), np.asarray(y_p))
 
 
 def test_execute_gemm_under_jit_and_vmap():
     x, dq = _exported_linear(jax.random.PRNGKey(3))
-    f = jax.jit(lambda a: execute_gemm(dq, a, backend="pallas"))
+    f = jax.jit(lambda a: execute_gemm(dq, a, backend=PALLAS))
     np.testing.assert_array_equal(
         np.asarray(f(x)), np.asarray(execute_gemm(dq, x, backend="oracle")))
     xb = jnp.stack([x, x * 0.5])
-    yb = jax.vmap(lambda a: execute_gemm(dq, a, backend="pallas"))(xb)
+    yb = jax.vmap(lambda a: execute_gemm(dq, a, backend=PALLAS))(xb)
     np.testing.assert_array_equal(
         np.asarray(yb[0]), np.asarray(execute_gemm(dq, x, backend="oracle")))
 
@@ -139,7 +142,7 @@ def test_moe_expert_bank_export_and_parity():
 
     x = jax.random.normal(jax.random.PRNGKey(2), (E, 3, cfg.d_model))
     y_o = execute_expert_gemm(dq, x, backend="oracle")
-    y_p = execute_expert_gemm(dq, x, backend="pallas")
+    y_p = execute_expert_gemm(dq, x, backend=PALLAS)
     np.testing.assert_array_equal(np.asarray(y_o), np.asarray(y_p))
     # per-expert slicing is exactly execute_gemm on each expert's codes
     import dataclasses
@@ -171,7 +174,7 @@ def test_moe_scan_stacked_expert_export_and_decode():
     assert report["unit.0.ffn.wi"]["n_experts"] == cfg.n_experts
     # deployed forward (scan over units slices the expert banks per unit)
     lg_o = forward(dep, cfg, tok, backend="oracle")
-    lg_p = forward(dep, cfg, tok, backend="pallas")
+    lg_p = forward(dep, cfg, tok, backend=PALLAS)
     np.testing.assert_array_equal(np.asarray(lg_o), np.asarray(lg_p))
     lg_fake = forward(snap_params_po2(p2), cfg, tok)
     err = float(jnp.max(jnp.abs(lg_o - lg_fake)))
@@ -211,7 +214,7 @@ def test_tied_head_calibrates_exports_and_serves():
                                   np.asarray(p2["embed"]["table"]))
     # deployed logits == snapped fake-quant logits (same PO2 grid)
     lg_dep = forward(dep, cfg, tok, backend="oracle")
-    lg_pal = forward(dep, cfg, tok, backend="pallas")
+    lg_pal = forward(dep, cfg, tok, backend=PALLAS)
     np.testing.assert_array_equal(np.asarray(lg_dep), np.asarray(lg_pal))
     lg_fake = forward(snap_params_po2(p2), cfg, tok)
     err = float(jnp.max(jnp.abs(lg_dep - lg_fake)))
@@ -223,7 +226,7 @@ def test_tied_head_calibrates_exports_and_serves():
 
 def test_engine_pallas_decode_equals_oracle_decode():
     """The tentpole acceptance: ServingEngine.from_exported with
-    backend="pallas" (interpret mode on CPU) greedy-decodes
+    the Pallas backend (interpret mode on CPU) greedy-decodes
     token-for-token identically to backend="oracle"."""
     from repro.serving import Request, ServingEngine
     cfg = _cfg(tie_embeddings=True)
@@ -275,9 +278,9 @@ def test_kv_attention_op_family_backend_parity():
     kc, ke = quantize_kv_po2(k)
     vc, ve = quantize_kv_po2(v)
     L = jnp.asarray([17, 64], jnp.int32)
-    outs = {be: execute_kv_attention(q, kc, vc, ke, ve, L, block_s=32,
-                                     backend=be)
-            for be in ("oracle", "pallas", "auto")}
+    outs = {getattr(be, "name", be): execute_kv_attention(
+                q, kc, vc, ke, ve, L, block_s=32, backend=be)
+            for be in ("oracle", PALLAS, "auto")}
     np.testing.assert_allclose(np.asarray(outs["pallas"]),
                                np.asarray(outs["oracle"]),
                                rtol=2e-5, atol=2e-6)
@@ -302,7 +305,7 @@ def test_kv_attention_scalar_length_and_block_rounding():
     kc, ke = quantize_kv_po2(k)
     vc, ve = quantize_kv_po2(v)
     a = execute_kv_attention(q, kc, vc, ke, ve, 20, block_s=20,
-                             backend="pallas")
+                             backend=PALLAS)
     b = execute_kv_attention(q, kc, vc, ke, ve,
                              jnp.asarray([20], jnp.int32), backend="oracle")
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),

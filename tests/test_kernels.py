@@ -196,6 +196,27 @@ def test_ragged_k_equals_explicitly_padded():
                                   np.asarray(baseline_matmul_ref(x, w)))
 
 
+@pytest.mark.parametrize("k,n_p", [(45, 4), (5632 // 8, 8), (300, 3),
+                                   (256, 2), (96, 1)])
+def test_pad_psum_tiles_keeps_every_tile(k, n_p):
+    """Lane padding of each PSUM tile's K slice: the padded K is a whole
+    number of 128-lane tiles per PSUM tile, and Algorithm 1 on the padded
+    codes equals Algorithm 1 on the originals (no K index changes tile)."""
+    from repro.kernels.apsq_matmul.ops import LANE, pad_psum_tiles
+    key = jax.random.PRNGKey(k + n_p)
+    x = _codes(key, (2, 3, k))                  # leading (expert) axes too
+    w = _codes(jax.random.fold_in(key, 1), (2, k, 16))
+    xp, wp = pad_psum_tiles(x, w, n_p)
+    kp = xp.shape[-1]
+    assert wp.shape == (2, kp, 16) and kp % n_p == 0
+    assert n_p == 1 or (kp // n_p) % LANE == 0
+    for e in range(2):
+        exps = choose_exps(x[e], w[e], n_p=n_p, gs=2)
+        np.testing.assert_array_equal(
+            np.asarray(apsq_matmul_ref(xp[e], wp[e], exps, n_p=n_p, gs=2)),
+            np.asarray(apsq_matmul_ref(x[e], w[e], exps, n_p=n_p, gs=2)))
+
+
 # ---------------------------------------------------------------------------
 # m=1 decode fast path (single grid row, K reduction unrolled in-register)
 # ---------------------------------------------------------------------------

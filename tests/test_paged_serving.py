@@ -425,15 +425,17 @@ def _ref_single_steps(cfg, p, state, tokens, pos, table, h, *, active,
     return (jnp.stack(toks, 1), jnp.stack(ons, 1), state, pos, rng)
 
 
-def _horizon_case(cfg, backend, *, h=4, eos=(-1, -1, -1), remaining=(9, 9, 9)):
-    """Fused vs unfused horizon on a 3-slot batch (slot 2 rides inert)."""
+def _horizon_case(cfg, backend, *, h=4, eos=(-1, -1, -1), remaining=(9, 9, 9),
+                  first=7):
+    """Fused vs unfused horizon on a 3-slot batch (slot 2 rides inert);
+    ``first`` is the token slot 0 is fed."""
     from repro.models.model import decode_horizon_paged
     p = init_lm(jax.random.PRNGKey(1), cfg)
     B, P = 3, 4
     state = init_paged_decode_state(cfg, B, page_size=P, n_pages=16)
     table = jnp.asarray([[1, 2, 3], [4, 5, 6], [NULL_PAGE] * 3], jnp.int32)
     pos = jnp.asarray([0, 2, 0], jnp.int32)
-    tokens = jnp.asarray([[7], [11], [0]], jnp.int32)
+    tokens = jnp.asarray([[first], [11], [0]], jnp.int32)
     kw = dict(active=jnp.asarray([True, True, False]),
               budget=jnp.asarray([h, h, 0], jnp.int32),
               remaining=jnp.asarray(remaining, jnp.int32),
@@ -478,10 +480,13 @@ def test_horizon_mid_eos_and_exhaustion_bit_identical():
     page thereafter — bit-identical to the masked single-step path."""
     cfg = CFG
     # First pass to discover what slot 0 emits at step 1; use it as EOS.
-    (tok, _, _, _, _), _ = _horizon_case(cfg, "oracle")
+    # Slot 0 is fed a token whose step-0 and step-1 outputs differ, so
+    # the EOS first appears at step 1.
+    (tok, _, _, _, _), _ = _horizon_case(cfg, "oracle", first=3)
     eos0 = int(tok[0, 1])
+    assert int(tok[0, 0]) != eos0, tok
     fused, ref = _horizon_case(cfg, "oracle", eos=(eos0, -1, -1),
-                               remaining=(9, 2, 9))
+                               remaining=(9, 2, 9), first=3)
     _assert_bit_identical(fused, ref)
     f_on = np.asarray(fused[1])
     assert f_on[0].tolist() == [True, True, False, False]   # stopped at EOS
