@@ -6,14 +6,11 @@ request arrivals are a Poisson process over decode steps, prompt and
 output lengths are mixed, and every stream decodes greedily.  Reported:
 
   * tokens/s (aggregate decode throughput across all streams),
-  * prefill tokens/s (prompt tokens through the chunked-prefill forwards
-    divided by the wall time spent inside them),
   * p50/p99 per-token latency (wall-clock of the engine step that
     produced each token — a fused macro-step's wall is attributed to
     every token it drained) and p50/p99 time-to-first-token,
-  * a host-overhead breakdown per load cell: wall time split into the
-    fused-decode window, the prefill-chunk window, and residual host
-    bookkeeping, plus dispatches per token and the macro-step scan-
+  * the engine's dispatch counters per load cell: wall time, prefill and
+    decode dispatches, dispatches per token and the macro-step scan-
     length histogram (``host_breakdown``),
   * a ``--decode-horizon`` sweep section (``horizon_sweep`` records):
     a saturated decode-bound cell — every stream generates the same
@@ -59,12 +56,12 @@ from repro.serving import PagedServingEngine, Request, paged_cache_bytes
 
 def _engine(params, cfg, *, max_batch, n_pages, backend="auto",
             page_size=16, prefill_chunk=16, max_pages_per_slot=None,
-            decode_horizon=8, profile=True):
+            decode_horizon=8):
     return PagedServingEngine(
         params, cfg, max_batch=max_batch, page_size=page_size,
         n_pages=n_pages, prefill_chunk=prefill_chunk, backend=backend,
         max_pages_per_slot=max_pages_per_slot,
-        decode_horizon=decode_horizon, profile=profile)
+        decode_horizon=decode_horizon)
 
 
 def _requests(cfg, n_streams, rng, *, max_new_lo=4, max_new_hi=12,
@@ -187,28 +184,19 @@ def run_load(params, cfg, *, n_streams, max_batch, arrival_rate,
                                max_batch=max_batch,
                                cache_len=per_slot * page_size)
     stats = eng.sched.stats
-    prefill_tps = (eng.prefill_tokens / eng.prefill_seconds
-                   if eng.prefill_seconds else 0.0)
-    # Host-overhead breakdown: wall splits into the fused-decode window
-    # (dispatch -> token-block drain, device compute included), the
-    # prefill-chunk window (profile=True syncs it), and what's left —
-    # pure host bookkeeping (scheduler, page walks, request churn).
-    host_s = max(wall - eng.decode_seconds - eng.prefill_seconds, 0.0)
     dispatches = eng.decode_dispatches + eng.prefill_dispatches
     print_fn(
         f"serving,{section},streams={n_streams},max_batch={max_batch},"
         f"decode_horizon={decode_horizon},"
         f"steps={step},tokens={total_tokens},"
         f"tokens_per_s={total_tokens / wall:.1f},"
-        f"prefill_tokens_per_s={prefill_tps:.1f},"
         f"p50_ms={np.percentile(lat_ms, 50):.1f},"
         f"p99_ms={np.percentile(lat_ms, 99):.1f},"
         f"ttft_p50_ms={np.percentile(ttft_ms, 50):.1f},"
         f"ttft_p99_ms={np.percentile(ttft_ms, 99):.1f},"
         f"admitted={stats.admitted},preempted={stats.preempted}")
     print_fn(
-        f"serving,{section}_host,decode_s={eng.decode_seconds:.3f},"
-        f"prefill_s={eng.prefill_seconds:.3f},host_s={host_s:.3f},"
+        f"serving,{section}_host,"
         f"wall_s={wall:.3f},dispatches={dispatches},"
         f"dispatches_per_token={dispatches / max(total_tokens, 1):.3f},"
         f"device_steps={eng.decode_device_steps}")
@@ -224,7 +212,6 @@ def run_load(params, cfg, *, n_streams, max_batch, arrival_rate,
         "steps": step, "tokens": total_tokens,
         "tokens_per_s": round(total_tokens / wall, 1),
         "prefill_tokens": int(eng.prefill_tokens),
-        "prefill_tokens_per_s": round(prefill_tps, 1),
         "p50_ms": round(float(np.percentile(lat_ms, 50)), 2),
         "p99_ms": round(float(np.percentile(lat_ms, 99)), 2),
         "ttft_p50_ms": round(float(np.percentile(ttft_ms, 50)), 2),
@@ -232,9 +219,6 @@ def run_load(params, cfg, *, n_streams, max_batch, arrival_rate,
         "admitted": stats.admitted, "preempted": stats.preempted,
         "host_breakdown": {
             "wall_s": round(wall, 4),
-            "decode_s": round(eng.decode_seconds, 4),
-            "prefill_s": round(eng.prefill_seconds, 4),
-            "host_s": round(host_s, 4),
             "decode_dispatches": eng.decode_dispatches,
             "prefill_dispatches": eng.prefill_dispatches,
             "dispatches_per_token": round(
@@ -288,15 +272,12 @@ def run_horizon_sweep(params, cfg, *, n_streams, max_batch, horizons,
         eng.sched.assert_invariants()
         total_tokens = sum(len(r.out) for r in done)
         assert total_tokens == n_streams * max_new
-        host_s = max(wall - eng.decode_seconds - eng.prefill_seconds, 0.0)
         dispatches = eng.decode_dispatches + eng.prefill_dispatches
         print_fn(
             f"serving,horizon_sweep,streams={n_streams},"
             f"max_batch={max_batch},decode_horizon={h},"
             f"max_new={max_new},tokens={total_tokens},"
             f"tokens_per_s={total_tokens / wall:.1f},"
-            f"decode_s={eng.decode_seconds:.3f},"
-            f"prefill_s={eng.prefill_seconds:.3f},host_s={host_s:.3f},"
             f"dispatches={dispatches},"
             f"device_steps={eng.decode_device_steps}")
         if records is not None:
@@ -308,9 +289,6 @@ def run_horizon_sweep(params, cfg, *, n_streams, max_batch, horizons,
                 "tokens_per_s": round(total_tokens / wall, 1),
                 "host_breakdown": {
                     "wall_s": round(wall, 4),
-                    "decode_s": round(eng.decode_seconds, 4),
-                    "prefill_s": round(eng.prefill_seconds, 4),
-                    "host_s": round(host_s, 4),
                     "decode_dispatches": eng.decode_dispatches,
                     "prefill_dispatches": eng.prefill_dispatches,
                     "dispatches_per_token": round(

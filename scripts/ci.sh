@@ -39,9 +39,9 @@
 #                                 == single-stream, oracle == interpret-
 #                                 mode pallas, AND fused horizon ==
 #                                 per-token heartbeats, token-for-token —
-#                                 before reporting tokens/s, prefill
-#                                 tokens/s, p50/p99 and the host-overhead
-#                                 breakdown into BENCH_serving.json.  The
+#                                 before reporting tokens/s, p50/p99 and
+#                                 the dispatch counters into
+#                                 BENCH_serving.json.  The
 #                                 fresh run is then gated against the
 #                                 committed BENCH_serving.json tokens/s +
 #                                 ttft_p50 floors AND its own h8-vs-h1

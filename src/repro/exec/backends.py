@@ -347,14 +347,19 @@ def execute_gemm(dq: DeployedQuantState, x: jax.Array, *,
     spec = dq.spec or QuantConfig.w8a8()
     k = dq.w_codes.shape[-2]
     out_shape = x.shape[:-1] + dq.out_dims
-    xc = quantize_activations(x.reshape(-1, k), dq.ax_exp, spec.a_bits)
     gs = 1
     if dq.psum_exps is not None:
         n_p = int(dq.psum_exps.shape[0])
         gs = n_p if spec.psum.mode == "psq" else spec.psum.gs
-    y = backend.int_gemm(xc, dq.w_codes, dq.psum_exps, gs=gs)
-    scale = jnp.exp2((dq.ax_exp + dq.aw_exp).astype(jnp.float32))
-    return (y.astype(jnp.float32) * scale).astype(x.dtype).reshape(out_shape)
+    with jax.named_scope("apsq_gemm"):
+        with jax.named_scope("quantize"):
+            xc = quantize_activations(x.reshape(-1, k), dq.ax_exp,
+                                      spec.a_bits)
+        y = backend.int_gemm(xc, dq.w_codes, dq.psum_exps, gs=gs)
+        with jax.named_scope("rescale"):
+            scale = jnp.exp2((dq.ax_exp + dq.aw_exp).astype(jnp.float32))
+            return (y.astype(jnp.float32) * scale).astype(
+                x.dtype).reshape(out_shape)
 
 
 def kv_block_size(seq_len: int, requested: int = 512) -> int:
@@ -371,6 +376,7 @@ def kv_block_size(seq_len: int, requested: int = 512) -> int:
     return b
 
 
+@jax.named_scope("kv_attention")
 def execute_kv_attention(q: jax.Array, k_codes: jax.Array,
                          v_codes: jax.Array, k_exp: jax.Array,
                          v_exp: jax.Array, length: jax.Array, *,
@@ -456,15 +462,21 @@ def execute_expert_gemm(dq: DeployedQuantState, x: jax.Array, *,
     n_exp = int(dq.w_codes.shape[0])
     k = int(dq.w_codes.shape[-2])
     out_shape = x.shape[:-1] + dq.out_dims
-    xc = quantize_activations(x.reshape(n_exp, -1, k),
-                              dq.ax_exp.reshape(n_exp, 1, 1), spec.a_bits)
     gs = 1
     if dq.psum_exps is not None:
         n_p = int(dq.psum_exps.shape[1])
         gs = n_p if spec.psum.mode == "psq" else spec.psum.gs
-    y = backend.int_expert_gemm(xc, dq.w_codes, dq.psum_exps, gs=gs)
-    aw = dq.aw_exp
-    aw = aw.reshape(n_exp, 1, -1) if aw.ndim > 1 else aw.reshape(n_exp, 1, 1)
-    scale = jnp.exp2((dq.ax_exp.reshape(n_exp, 1, 1) + aw)
-                     .astype(jnp.float32))
-    return (y.astype(jnp.float32) * scale).astype(x.dtype).reshape(out_shape)
+    with jax.named_scope("apsq_gemm"):
+        with jax.named_scope("quantize"):
+            xc = quantize_activations(x.reshape(n_exp, -1, k),
+                                      dq.ax_exp.reshape(n_exp, 1, 1),
+                                      spec.a_bits)
+        y = backend.int_expert_gemm(xc, dq.w_codes, dq.psum_exps, gs=gs)
+        with jax.named_scope("rescale"):
+            aw = dq.aw_exp
+            aw = (aw.reshape(n_exp, 1, -1) if aw.ndim > 1
+                  else aw.reshape(n_exp, 1, 1))
+            scale = jnp.exp2((dq.ax_exp.reshape(n_exp, 1, 1) + aw)
+                             .astype(jnp.float32))
+            return (y.astype(jnp.float32) * scale).astype(
+                x.dtype).reshape(out_shape)

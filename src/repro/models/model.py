@@ -189,86 +189,90 @@ def apply_layer(
     # residual stream added reshards — GSPMD already propagates SP from
     # the ddlerp/rglru hints.  Left unconstrained.)
     quant = cfg.quant if cfg.quant.enabled else None
-    h = apply_norm(p["ln1"], x, cfg.norm)
-    new_state: Params = {}
+    with jax.named_scope("attn"):
+        h = apply_norm(p["ln1"], x, cfg.norm)
+        new_state: Params = {}
 
-    if kind in ("attn", "local"):
-        window = cfg.local_window if kind == "local" else None
-        if state is not None and "k_pages" in state:
-            cache = state  # paged INT8 pools + running exponents
-        elif state is not None:
-            cache = {"k": state["k"], "v": state["v"]}
+        if kind in ("attn", "local"):
+            window = cfg.local_window if kind == "local" else None
+            if state is not None and "k_pages" in state:
+                cache = state  # paged INT8 pools + running exponents
+            elif state is not None:
+                cache = {"k": state["k"], "v": state["v"]}
+            else:
+                cache = None
+            out, kv = attention_block(
+                p["mix"], h, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+                head_dim=cfg.hd, rope_fraction=cfg.rope_fraction,
+                rope_theta=cfg.rope_theta, causal=causal, window=window,
+                softcap=cfg.softcap, quant=quant, cache=cache, pos=pos,
+                mesh=mesh, tap=tap, backend=backend, page_table=page_table)
+            new_state = kv
+        elif kind == "rwkv":
+            # Chunked paged prefill must stay bit-identical to the per-token
+            # scan; the chunk-parallel WKV reorders fp32 accumulation, so
+            # force the sequential impl whenever a multi-token chunk runs
+            # against paged serving state.
+            exact = page_table is not None and x.shape[1] > 1
+            out, tm_state = rwkv_time_mix(
+                p["mix"], h, n_heads=cfg.n_heads, head_dim=cfg.hd, quant=quant,
+                impl="scan" if exact else cfg.wkv_impl,
+                wkv_chunk=cfg.wkv_chunk, mesh=mesh,
+                state=state["tm"] if state is not None else None, tap=tap,
+                backend=backend)
+            new_state = {"tm": tm_state}
+        elif kind == "rglru":
+            out, rec_state = rglru_block(
+                p["mix"], h, quant=quant, mesh=mesh,
+                state=state["rec"] if state is not None else None, tap=tap,
+                backend=backend,
+                exact_scan=page_table is not None and x.shape[1] > 1)
+            new_state = {"rec": rec_state}
         else:
-            cache = None
-        out, kv = attention_block(
-            p["mix"], h, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.hd, rope_fraction=cfg.rope_fraction,
-            rope_theta=cfg.rope_theta, causal=causal, window=window,
-            softcap=cfg.softcap, quant=quant, cache=cache, pos=pos,
-            mesh=mesh, tap=tap, backend=backend, page_table=page_table)
-        new_state = kv
-    elif kind == "rwkv":
-        # Chunked paged prefill must stay bit-identical to the per-token
-        # scan; the chunk-parallel WKV reorders fp32 accumulation, so
-        # force the sequential impl whenever a multi-token chunk runs
-        # against paged serving state.
-        exact = page_table is not None and x.shape[1] > 1
-        out, tm_state = rwkv_time_mix(
-            p["mix"], h, n_heads=cfg.n_heads, head_dim=cfg.hd, quant=quant,
-            impl="scan" if exact else cfg.wkv_impl,
-            wkv_chunk=cfg.wkv_chunk, mesh=mesh,
-            state=state["tm"] if state is not None else None, tap=tap,
-            backend=backend)
-        new_state = {"tm": tm_state}
-    elif kind == "rglru":
-        out, rec_state = rglru_block(
-            p["mix"], h, quant=quant, mesh=mesh,
-            state=state["rec"] if state is not None else None, tap=tap,
-            backend=backend,
-            exact_scan=page_table is not None and x.shape[1] > 1)
-        new_state = {"rec": rec_state}
-    else:
-        raise ValueError(kind)
-    x = x + out
+            raise ValueError(kind)
+        x = x + out
 
-    if "xattn" in p and enc_out is not None:
-        hx = apply_norm(p["lnx"], x, cfg.norm)
-        outx, _ = attention_block(
-            p["xattn"], hx, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.hd, quant=quant, xkv=enc_out, use_rope=False,
-            mesh=mesh, tap=tap, backend=backend)
-        x = x + outx
+        if "xattn" in p and enc_out is not None:
+            hx = apply_norm(p["lnx"], x, cfg.norm)
+            outx, _ = attention_block(
+                p["xattn"], hx, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+                head_dim=cfg.hd, quant=quant, xkv=enc_out, use_rope=False,
+                mesh=mesh, tap=tap, backend=backend)
+            x = x + outx
 
-    h2 = apply_norm(p["ln2"], x, cfg.norm)
-    if cfg.mlp == "moe":
-        from repro.core import DeployedQuantState
-        deployed_moe = isinstance(p["ffn"].get("qp_wi"), DeployedQuantState)
-        if mesh is not None and not deployed_moe:
-            y = moe_ffn_sharded(p["ffn"], h2, mesh=mesh,
-                                n_experts=cfg.n_experts, top_k=cfg.top_k,
-                                capacity_factor=cfg.capacity_factor,
-                                quant=quant, backend=backend)
+    with jax.named_scope("mlp"):
+        h2 = apply_norm(p["ln2"], x, cfg.norm)
+        if cfg.mlp == "moe":
+            from repro.core import DeployedQuantState
+            deployed_moe = isinstance(p["ffn"].get("qp_wi"),
+                                      DeployedQuantState)
+            if mesh is not None and not deployed_moe:
+                y = moe_ffn_sharded(p["ffn"], h2, mesh=mesh,
+                                    n_experts=cfg.n_experts, top_k=cfg.top_k,
+                                    capacity_factor=cfg.capacity_factor,
+                                    quant=quant, backend=backend)
+            else:
+                # Deployed expert banks: EP lives INSIDE the backend
+                # (``ShardedBackend.int_expert_gemm`` shard_maps the expert
+                # axis and gathers outputs as INT8 codes), so the pure path
+                # is the right wrapper — ``moe_ffn_sharded``'s fp32 psum
+                # combine would both double-wrap shard_map and lose the
+                # int8-on-the-wire saving.
+                y = moe_ffn(p["ffn"], h2, n_experts=cfg.n_experts,
+                            top_k=cfg.top_k,
+                            capacity_factor=cfg.capacity_factor,
+                            quant=quant, tap=tap, backend=backend)
+        elif cfg.mlp == "rwkv_cm":
+            y, cm_state = rwkv_channel_mix(
+                p["ffn"], h2, quant=quant, mesh=mesh,
+                state=state["cm"] if (state is not None and "cm" in state)
+                else None, tap=tap, backend=backend)
+            if state is not None:
+                new_state["cm"] = cm_state
         else:
-            # Deployed expert banks: EP lives INSIDE the backend
-            # (``ShardedBackend.int_expert_gemm`` shard_maps the expert
-            # axis and gathers outputs as INT8 codes), so the pure path
-            # is the right wrapper — ``moe_ffn_sharded``'s fp32 psum
-            # combine would both double-wrap shard_map and lose the
-            # int8-on-the-wire saving.
-            y = moe_ffn(p["ffn"], h2, n_experts=cfg.n_experts,
-                        top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
-                        quant=quant, tap=tap, backend=backend)
-    elif cfg.mlp == "rwkv_cm":
-        y, cm_state = rwkv_channel_mix(
-            p["ffn"], h2, quant=quant, mesh=mesh,
-            state=state["cm"] if (state is not None and "cm" in state)
-            else None, tap=tap, backend=backend)
-        if state is not None:
-            new_state["cm"] = cm_state
-    else:
-        y = apply_mlp(p["ffn"], h2, kind=cfg.mlp, quant=quant, tap=tap,
-                      backend=backend)
-    x = x + y
+            y = apply_mlp(p["ffn"], h2, kind=cfg.mlp, quant=quant, tap=tap,
+                          backend=backend)
+        x = x + y
     # RWKV layers always carry channel-mix shift state in decode.
     if kind == "rwkv" and state is not None and "cm" not in new_state:
         new_state["cm"] = {"shift": h2[:, -1:]}
@@ -455,23 +459,24 @@ def logits_from_hidden(p: Params, cfg: ModelConfig, x: jax.Array,
     from repro.core import (DeployedQuantState, QuantState, quant_dense,
                             tied_head_weight)
     from .common import act_spec, shard_hint
-    x = apply_norm(p["final_norm"], x, cfg.norm)
-    if cfg.tie_embeddings:
-        # The tied head GEMM is quantizable like any projection: a
-        # ``qp_head`` state appears after ``calibrate_model`` (fake-quant
-        # QAT view over ``tied_head_weight(table)``) and
-        # ``export_quantized`` deploys it as INT8 codes + shift exponents
-        # routed through the exec backend.
-        qp_head = p["embed"].get("qp_head")
-        if isinstance(qp_head, DeployedQuantState):
-            logits = quant_dense(x, None, qp_head, backend=backend)
-        elif isinstance(qp_head, QuantState):
-            logits = quant_dense(x, tied_head_weight(p["embed"]["table"]),
-                                 qp_head)
+    with jax.named_scope("lm_head"):
+        x = apply_norm(p["final_norm"], x, cfg.norm)
+        if cfg.tie_embeddings:
+            # The tied head GEMM is quantizable like any projection: a
+            # ``qp_head`` state appears after ``calibrate_model``
+            # (fake-quant QAT view over ``tied_head_weight(table)``) and
+            # ``export_quantized`` deploys it as INT8 codes + shift
+            # exponents routed through the exec backend.
+            qp_head = p["embed"].get("qp_head")
+            if isinstance(qp_head, DeployedQuantState):
+                logits = quant_dense(x, None, qp_head, backend=backend)
+            elif isinstance(qp_head, QuantState):
+                logits = quant_dense(
+                    x, tied_head_weight(p["embed"]["table"]), qp_head)
+            else:
+                logits = jnp.einsum("bsd,vd->bsv", x, p["embed"]["table"])
         else:
-            logits = jnp.einsum("bsd,vd->bsv", x, p["embed"]["table"])
-    else:
-        logits = dense(p["head"], x, None, backend=backend)
+            logits = dense(p["head"], x, None, backend=backend)
     return shard_hint(logits, act_spec(mesh, x.shape[0], feat=cfg.vocab))
 
 
@@ -685,6 +690,15 @@ def forward_paged_chunk(
     states match the token-by-token path bit-for-bit too.
 
     Returns (logits [B, 1, V] for the LAST chunk row, new_state)."""
+    with jax.named_scope("prefill"):
+        return _forward_paged(p, cfg, state, tokens, pos, page_table,
+                              mesh=mesh, backend=backend)
+
+
+def _forward_paged(p, cfg, state, tokens, pos, page_table, *, mesh,
+                   backend):
+    """The forward shared by ``forward_paged_chunk`` (named ``prefill``
+    in the compiled program's op metadata) and ``decode_step_paged``."""
     x = jnp.take(p["embed"]["table"], tokens, axis=0)
 
     new_state = dict(state)
@@ -735,8 +749,8 @@ def decode_step_paged(
     [B, n_max] maps each slot's logical pages to physical pool pages.
     Returns (logits [B, 1, V], new_state).  This is exactly
     ``forward_paged_chunk`` with a chunk of one token."""
-    return forward_paged_chunk(p, cfg, state, token, pos, page_table,
-                               mesh=mesh, backend=backend)
+    return _forward_paged(p, cfg, state, token, pos, page_table,
+                          mesh=mesh, backend=backend)
 
 
 # ---------------------------------------------------------------------------
@@ -840,16 +854,17 @@ def decode_horizon_paged(
                                     mesh=mesh, backend=backend)
         st2 = jax.tree.map(lambda o, n, ax: _keep_slots(o, n, ax, on),
                            st, st2, axes)
-        logits = lg[:, -1] / temp
-        key, sub = jax.random.split(key)
-        if greedy:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            nxt = jax.random.categorical(sub, logits,
-                                         axis=-1).astype(jnp.int32)
-        rem2 = jnp.where(on, rem - 1, rem)
-        fin = on & ((nxt == eos) | (rem2 <= 0))
-        tok2 = jnp.where(on, jnp.where(fin, 0, nxt), tok[:, 0])[:, None]
+        with jax.named_scope("sample"):
+            logits = lg[:, -1] / temp
+            key, sub = jax.random.split(key)
+            if greedy:
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                nxt = jax.random.categorical(sub, logits,
+                                             axis=-1).astype(jnp.int32)
+            rem2 = jnp.where(on, rem - 1, rem)
+            fin = on & ((nxt == eos) | (rem2 <= 0))
+            tok2 = jnp.where(on, jnp.where(fin, 0, nxt), tok[:, 0])[:, None]
         carry2 = (st2, tok2, ps + on.astype(ps.dtype), act & ~fin,
                   bud - on.astype(bud.dtype), rem2, key)
         return carry2, (nxt, on)
@@ -857,8 +872,9 @@ def decode_horizon_paged(
     carry = (state, tokens, jnp.asarray(pos, jnp.int32), active,
              jnp.asarray(budget, jnp.int32), jnp.asarray(remaining,
                                                          jnp.int32), rng)
-    (st, _, ps, _, _, _, key), (toks, ons) = jax.lax.scan(
-        body, carry, None, length=horizon)
+    with jax.named_scope("decode"):
+        (st, _, ps, _, _, _, key), (toks, ons) = jax.lax.scan(
+            body, carry, None, length=horizon)
     return (jnp.moveaxis(toks, 0, 1), jnp.moveaxis(ons, 0, 1), st, ps, key)
 
 

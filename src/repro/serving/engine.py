@@ -106,6 +106,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models.config import ModelConfig
 from repro.models.model import (
@@ -131,12 +132,21 @@ def _check_horizon(h) -> int:
 
 @dataclasses.dataclass
 class Request:
+    """One generation request.  The paged engine stamps its lifecycle on
+    the host clock (``time.perf_counter``): ``t_submit`` when it is
+    queued, ``t_admit`` when it first gets a slot (a preemption does not
+    reset it) and ``t_prefilled`` when its prompt's first token reached
+    the host.  [t_submit, t_admit] is its queue wait, [t_admit,
+    t_prefilled] its prefill."""
     uid: int
     tokens: np.ndarray            # prompt
     max_new_tokens: int = 32
     eos_token: int | None = None  # stop when this token is generated
     out: list = dataclasses.field(default_factory=list)
     done: bool = False
+    t_submit: float | None = None
+    t_admit: float | None = None
+    t_prefilled: float | None = None
 
     def hit_eos(self) -> bool:
         return (self.eos_token is not None and len(self.out) > 0
@@ -179,7 +189,7 @@ class ServingEngine:
                  cache_len: int = 1024, prefill_chunk: int = 64,
                  decode_horizon: int = 1,
                  mesh=None, greedy: bool = True, temperature: float = 1.0,
-                 seed: int = 0, backend="auto", profile: bool = False):
+                 seed: int = 0, backend="auto"):
         from repro.exec import get_backend
         self.params = params
         self.cfg = cfg
@@ -192,7 +202,6 @@ class ServingEngine:
         # reference engine on the classic one-token heartbeat; the paged
         # engine defaults to 8 (see PagedServingEngine).
         self.decode_horizon = _check_horizon(decode_horizon)
-        self.profile = profile
         self.mesh = mesh
         self.greedy = greedy
         self.temperature = temperature
@@ -219,11 +228,10 @@ class ServingEngine:
         self._prefill = jax.jit(self._prefill_impl)
 
     def reset_counters(self) -> None:
-        """Zero the dispatch/latency counters (benchmarks call this after
-        warmup so compile time stays out of the measured window)."""
+        """Zero the dispatch counters (benchmarks call this after warmup
+        so compile time stays out of the measured window)."""
         self.decode_dispatches = 0     # jitted decode launches
         self.decode_device_steps = 0   # scan steps across those launches
-        self.decode_seconds = 0.0      # wall time dispatch -> token drain
         self.horizon_hist: dict[int, int] = {}  # scan length -> launches
 
     @classmethod
@@ -378,14 +386,12 @@ class ServingEngine:
         if self._pos_dirty:
             self._pos_dev = jnp.asarray(self.pos)
             self._pos_dirty = False
-        t0 = time.perf_counter()
         blk, em, self.state, self._pos_dev, self.rng = self._decode(
             h, self.params, self.state, jnp.asarray(tokens), self._pos_dev,
             jnp.asarray(mask), jnp.asarray(bud), jnp.asarray(rem),
             jnp.asarray(eos), self.rng)
         blk = np.asarray(blk)
         em = np.asarray(em)
-        self.decode_seconds += time.perf_counter() - t0
         self.decode_dispatches += 1
         self.decode_device_steps += h
         self.horizon_hist[h] = self.horizon_hist.get(h, 0) + 1
@@ -451,9 +457,14 @@ class PagedServingEngine:
         (tight page pools, strict per-token SLO).  ``_ensure_capacity``
         pre-reserves each slot's pages over [pos, pos+H) and shrinks the
         slot's budget instead of preempting when the pool is tight.
-      * ``profile``              — re-enable the per-prefill-chunk
-        ``block_until_ready`` timing sync (fills ``prefill_seconds``);
-        off by default so chunk dispatches overlap on device.
+
+    Tracing: ``step`` writes host spans named ``engine.*`` (admission,
+    prefill with its first-token syncs, page capacity, decode launch and
+    drain, token bookkeeping) that a ``jax.profiler`` session records and
+    that cost nothing without one; the jitted programs carry
+    ``jax.named_scope`` paths (``decode``, ``prefill``, ``attn``,
+    ``mlp``, ``kv_update``, ``kv_attention``, ``apsq_gemm``, ...) in
+    their ops' metadata; each ``Request`` carries its lifecycle stamps.
     """
 
     def __init__(self, params, cfg: ModelConfig, *, max_batch: int = 8,
@@ -464,7 +475,7 @@ class PagedServingEngine:
                  decode_horizon: int = 8,
                  mesh=None, greedy: bool = True,
                  temperature: float = 1.0, seed: int = 0, backend="auto",
-                 wire: str = "int8", profile: bool = False):
+                 wire: str = "int8"):
         from repro.exec import get_backend
         from .scheduler import Scheduler
         if any(k == "local" for k in cfg.block_pattern) or cfg.softcap:
@@ -489,10 +500,6 @@ class PagedServingEngine:
         # the slot's budget (never preempting) when the pool is tight.
         # 1 degenerates to the classic one-token heartbeat.
         self.decode_horizon = _check_horizon(decode_horizon)
-        # profile=True restores the per-prefill-chunk block_until_ready
-        # timing sync (prefill_seconds) and decode timing; off (default),
-        # prefill chunks of co-resident slots overlap their dispatch.
-        self.profile = profile
         self.mesh = mesh
         self.greedy = greedy
         self.temperature = temperature
@@ -538,14 +545,12 @@ class PagedServingEngine:
         self._prefill_chunk = jax.jit(self._prefill_chunk_impl)
 
     def reset_counters(self) -> None:
-        """Zero dispatch/latency counters (benchmarks call this after the
-        warmup request so compile time stays out of the window)."""
+        """Zero dispatch counters (benchmarks call this after the warmup
+        request so compile time stays out of the window)."""
         self.prefill_tokens = 0      # prompt tokens pushed through chunks
-        self.prefill_seconds = 0.0   # wall in chunk forwards (profile=True)
         self.prefill_dispatches = 0  # prefill chunk launches
         self.decode_dispatches = 0   # fused decode launches
         self.decode_device_steps = 0  # scan steps across those launches
-        self.decode_seconds = 0.0    # wall time dispatch -> token drain
         self.horizon_hist: dict[int, int] = {}  # scan length -> launches
 
     @classmethod
@@ -654,7 +659,9 @@ class PagedServingEngine:
         of two <= ``prefill_chunk`` (so every chunk is fully valid — no
         pad masking — and the chunk body compiles for at most
         log2(prefill_chunk)+1 shapes).  The final chunk's logits produce
-        the request's first output token, exactly like a decode step."""
+        the request's first output token, exactly like a decode step, and
+        reaches the host right after that chunk (stamping
+        ``t_prefilled``)."""
         budget = self.prefill_token_budget
         order = sorted(self._mid_prefill,
                        key=lambda s: self.sched._admitted_at[s])
@@ -668,18 +675,11 @@ class PagedServingEngine:
                 c = 1 << (c.bit_length() - 1)         # pow2 chunk sizes
                 if not self._grow_range(s, done, done + c):
                     return                            # pool dry: pause
-                t0 = time.perf_counter() if self.profile else 0.0
                 self.state, logits = self._prefill_chunk(
                     self.params, self.state,
                     jnp.asarray(resume[done:done + c][None]),
                     jnp.asarray(s, jnp.int32), jnp.asarray(done, jnp.int32),
                     jnp.asarray(self.sched.table[s:s + 1]))
-                if self.profile:
-                    # Timing sync only under profile=: the default path
-                    # leaves chunk dispatches of co-resident slots free
-                    # to overlap on device.
-                    logits.block_until_ready()
-                    self.prefill_seconds += time.perf_counter() - t0
                 self.prefill_dispatches += 1
                 self.prefill_tokens += c
                 self.pos[s] = done + c
@@ -688,6 +688,8 @@ class PagedServingEngine:
                 if done + c == len(resume):           # prompt fully cached
                     req = self.sched.slots[s]
                     req.out.append(int(jnp.argmax(logits[0])))
+                    if req.t_prefilled is None:
+                        req.t_prefilled = time.perf_counter()
                     del self._mid_prefill[s]
                     if len(req.out) >= req.max_new_tokens or req.hit_eos():
                         req.done = True               # swept by step()
@@ -756,13 +758,14 @@ class PagedServingEngine:
         completes) its successor's prefill in the same heartbeat instead
         of idling until the next one — under slot contention that saves
         one full decode step of TTFT per queued request."""
-        self._admit()
-        self._prefill_step()
-        finished = []
-        for s, r in enumerate(self.sched.slots):
-            if r is not None and r.done:              # done on prefill token
-                finished.append(self.sched.finish(s))
-        return finished
+        with TraceAnnotation("engine.admit"):
+            self._admit()
+        with TraceAnnotation("engine.prefill"):
+            self._prefill_step()
+        with TraceAnnotation("engine.sweep"):
+            return [self.sched.finish(s)
+                    for s, r in enumerate(self.sched.slots)
+                    if r is not None and r.done]      # done on prefill token
 
     def step(self) -> list:
         """One continuous-batching heartbeat: admit (slot + first-chunk
@@ -775,55 +778,59 @@ class PagedServingEngine:
         [B, H] token block, and finally re-admit into any slots the
         decode sweep freed."""
         finished = self._admit_and_prefill()
-        fin_cap, budgets = self._ensure_capacity(self.decode_horizon)
+        with TraceAnnotation("engine.capacity"):
+            fin_cap, budgets = self._ensure_capacity(self.decode_horizon)
         finished.extend(fin_cap)
         active = [s for s, r in enumerate(self.sched.slots)
                   if r is not None and s not in self._mid_prefill]
         if not active:
             return finished
-        B = self.max_batch
-        tokens = np.zeros((B, 1), np.int32)
-        mask = np.zeros(B, np.bool_)
-        rem = np.zeros(B, np.int32)
-        eos = np.full(B, -1, np.int32)
-        for s in active:
-            r = self.sched.slots[s]
-            tokens[s, 0] = r.out[-1]
-            mask[s] = True
-            rem[s] = r.max_new_tokens - len(r.out)
-            if r.eos_token is not None:
-                eos[s] = r.eos_token
-        # Zero the table rows of non-decoding slots: their (garbage)
-        # writes land on the null page instead of live cache pages.
-        table = np.where(mask[:, None], self.sched.table, NULL_PAGE)
-        # Scan just long enough for the biggest per-slot budget, snapped
-        # to pow2 (at most log2(decode_horizon)+1 compiled variants).
-        h = max(1, max(int(budgets[s]) for s in active))
-        h = 1 << (h - 1).bit_length()
-        if self._pos_dirty:
-            self._pos_dev = jnp.asarray(self.pos)
-            self._pos_dirty = False
-        t0 = time.perf_counter()
-        blk, em, self.state, self._pos_dev, self.rng = self._decode(
-            h, self.params, self.state, jnp.asarray(tokens), self._pos_dev,
-            jnp.asarray(table), jnp.asarray(mask), jnp.asarray(budgets),
-            jnp.asarray(rem), jnp.asarray(eos), self.rng)
-        blk = np.asarray(blk)     # the macro-step's single host sync
-        em = np.asarray(em)
-        self.decode_seconds += time.perf_counter() - t0
-        self.decode_dispatches += 1
-        self.decode_device_steps += h
-        self.horizon_hist[h] = self.horizon_hist.get(h, 0) + 1
-        for s in active:
-            r = self.sched.slots[s]
-            for t in range(h):
-                if not em[s, t]:
-                    break
-                r.out.append(int(blk[s, t]))
-                self.pos[s] += 1  # device pos advanced identically
-            if len(r.out) >= r.max_new_tokens or r.hit_eos():
-                r.done = True
-                finished.append(self.sched.finish(s))
+        with TraceAnnotation("engine.decode.launch"):
+            B = self.max_batch
+            tokens = np.zeros((B, 1), np.int32)
+            mask = np.zeros(B, np.bool_)
+            rem = np.zeros(B, np.int32)
+            eos = np.full(B, -1, np.int32)
+            for s in active:
+                r = self.sched.slots[s]
+                tokens[s, 0] = r.out[-1]
+                mask[s] = True
+                rem[s] = r.max_new_tokens - len(r.out)
+                if r.eos_token is not None:
+                    eos[s] = r.eos_token
+            # Zero the table rows of non-decoding slots: their (garbage)
+            # writes land on the null page instead of live cache pages.
+            table = np.where(mask[:, None], self.sched.table, NULL_PAGE)
+            # Scan just long enough for the biggest per-slot budget,
+            # snapped to pow2 (at most log2(decode_horizon)+1 compiled
+            # variants).
+            h = max(1, max(int(budgets[s]) for s in active))
+            h = 1 << (h - 1).bit_length()
+            if self._pos_dirty:
+                self._pos_dev = jnp.asarray(self.pos)
+                self._pos_dirty = False
+            blk, em, self.state, self._pos_dev, self.rng = self._decode(
+                h, self.params, self.state, jnp.asarray(tokens),
+                self._pos_dev, jnp.asarray(table), jnp.asarray(mask),
+                jnp.asarray(budgets), jnp.asarray(rem), jnp.asarray(eos),
+                self.rng)
+        with TraceAnnotation("engine.decode.drain"):
+            blk = np.asarray(blk)     # the macro-step's single host sync
+            em = np.asarray(em)
+        with TraceAnnotation("engine.sweep"):
+            self.decode_dispatches += 1
+            self.decode_device_steps += h
+            self.horizon_hist[h] = self.horizon_hist.get(h, 0) + 1
+            for s in active:
+                r = self.sched.slots[s]
+                for t in range(h):
+                    if not em[s, t]:
+                        break
+                    r.out.append(int(blk[s, t]))
+                    self.pos[s] += 1  # device pos advanced identically
+                if len(r.out) >= r.max_new_tokens or r.hit_eos():
+                    r.done = True
+                    finished.append(self.sched.finish(s))
         if self.sched.waiting:                        # refill freed slots now
             finished.extend(self._admit_and_prefill())
         return finished

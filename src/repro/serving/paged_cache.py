@@ -95,6 +95,7 @@ def _bump_token(gathered: jax.Array, exp: jax.Array, x_new: jax.Array,
     return gathered, new_exp
 
 
+@jax.named_scope("kv_update")
 def _update_pool(pages: jax.Array, exp: jax.Array, x_new: jax.Array,
                  pos: jax.Array, page_table: jax.Array):
     """Write one token per slot into the paged pool.
@@ -109,6 +110,7 @@ def _update_pool(pages: jax.Array, exp: jax.Array, x_new: jax.Array,
     return pages, new_exp, gathered
 
 
+@jax.named_scope("kv_update")
 def _update_pool_chunk(pages: jax.Array, exp: jax.Array, x_new: jax.Array,
                        pos: jax.Array, page_table: jax.Array):
     """Write a [chunk] of tokens per slot with the per-token bump sequence.

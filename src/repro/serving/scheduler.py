@@ -32,6 +32,7 @@ entries = NULL_PAGE) into the jitted decode's gather indices.
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 
 import numpy as np
@@ -122,13 +123,16 @@ class Scheduler:
     # -- queue -------------------------------------------------------------
 
     def submit(self, req) -> None:
-        """Queue a request; rejects ones that could never run to completion."""
+        """Queue a request; rejects ones that could never run to completion.
+        Stamps ``req.t_submit`` (host clock) the first time."""
         need = self.pages_for(len(req.tokens) + req.max_new_tokens)
         if need > self.max_pages_per_slot:
             raise ValueError(
                 f"request {req.uid}: needs {need} pages "
                 f"(prompt {len(req.tokens)} + max_new {req.max_new_tokens} "
                 f"tokens) > per-slot budget {self.max_pages_per_slot}")
+        if req.t_submit is None:
+            req.t_submit = time.perf_counter()
         self.waiting.append(req)
 
     def admit_next(self):
@@ -141,7 +145,9 @@ class Scheduler:
         admission; with it only the first prefill chunk's do (later pages
         ``grow`` chunk by chunk).  Admission never evicts: if the pool
         cannot host the start of the prompt right now, the head waits for
-        running requests to drain.
+        running requests to drain.  The first admission stamps
+        ``req.t_admit`` (host clock); a re-admission after a preemption
+        keeps it.
         """
         if not self.waiting:
             return None
@@ -168,6 +174,8 @@ class Scheduler:
         self._admitted_at[slot] = self._admit_seq
         self.table[slot, :need] = pages
         self.stats.admitted += 1
+        if req.t_admit is None:
+            req.t_admit = time.perf_counter()
         return slot, req, resume
 
     # -- mid-decode --------------------------------------------------------

@@ -6,6 +6,8 @@ single-stream engine — under admission churn, a dry page pool with
 mid-decode eviction, and across exec backends (oracle vs interpret-mode
 Pallas).  The host-side scheduler never leaks a slot or a page.
 """
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -361,6 +363,109 @@ def test_mid_prefill_preemption_replays_exactly(params):
     assert {r.uid: r.out for r in done} == single
     eng.sched.assert_invariants()
     assert eng.sched.alloc.n_free == 7
+
+
+def test_request_lifecycle_stamps(params):
+    """Every request is stamped submit <= first admission <= first token
+    read on the host; a preempted request keeps its first admission's
+    stamp when it is admitted again."""
+    spec = [(i, _prompt(10 + i, seed=i), 6) for i in range(4)]
+    eng = _engine(params, max_batch=4, page_size=4, n_pages=8,
+                  prefill_chunk=4, prefill_token_budget=4,
+                  decode_horizon=1)
+    first_admit = {}
+    preempt = eng.sched.preempt
+
+    def preempt_noting_admission(slot):
+        r = eng.sched.slots[slot]
+        first_admit.setdefault(r.uid, r.t_admit)
+        return preempt(slot)
+
+    eng.sched.preempt = preempt_noting_admission
+    done = eng.run([Request(uid=u, tokens=t, max_new_tokens=n)
+                    for u, t, n in spec])
+    assert len(done) == len(spec)
+    assert first_admit, "pool was not small enough"
+    for r in done:
+        assert r.t_submit <= r.t_admit <= r.t_prefilled
+    by_uid = {r.uid: r for r in done}
+    for uid, t in first_admit.items():
+        assert t is not None and by_uid[uid].t_admit == t
+
+
+def test_first_token_stamped_in_the_heartbeat_of_its_last_chunk(params):
+    """A request is stamped ``t_prefilled`` in the heartbeat whose prefill
+    chunk finished its prompt, when its first token reached the host,
+    and not before; a request done on that token is stamped too."""
+    spec = [(i, _prompt(9 + 3 * i, seed=i), 1 + 4 * (i % 2))
+            for i in range(4)]
+    eng = _engine(params, max_batch=2, page_size=4, n_pages=16,
+                  prefill_chunk=4, prefill_token_budget=4)
+    reqs = [Request(uid=u, tokens=t, max_new_tokens=n) for u, t, n in spec]
+    for r in reqs:
+        eng.add_request(r)
+    done = []
+    while len(done) < len(reqs):
+        before = {r.uid: r.t_prefilled for r in reqs}
+        t0 = time.perf_counter()
+        done.extend(eng.step())
+        t1 = time.perf_counter()
+        for r in reqs:
+            assert (r.t_prefilled is None) == (not r.out)
+            if r.out and before[r.uid] is None:   # first token this beat
+                assert t0 <= r.t_prefilled <= t1
+            elif r.out:
+                assert r.t_prefilled == before[r.uid]
+    assert all(r.t_admit <= r.t_prefilled for r in reqs)
+    assert {r.uid for r in done if len(r.out) == 1} == {0, 2}
+
+
+@pytest.fixture(scope="module")
+def deployed_smoke():
+    """The smoke tinyllama config at APSQ W8A8, calibrated and exported,
+    so that every projection runs through ``execute_gemm``."""
+    import dataclasses
+
+    from repro.configs import get_smoke
+    from repro.core import QuantConfig
+    from repro.quant import calibrate_model, export_quantized
+    cfg = dataclasses.replace(get_smoke("tinyllama-1.1b"),
+                              quant=QuantConfig.apsq(gs=2, n_p=4))
+    p = init_lm(jax.random.PRNGKey(0), cfg)
+    tok = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab)
+    return cfg, export_quantized(calibrate_model(p, cfg, {"tokens": tok}))[0]
+
+
+@pytest.mark.parametrize("program,want", [
+    ("decode", {"decode", "sample", "lm_head", "attn", "mlp", "kv_update",
+                "kv_attention", "apsq_gemm", "quantize", "rescale"}),
+    ("prefill", {"prefill", "lm_head", "attn", "mlp", "kv_update",
+                 "kv_attention", "apsq_gemm", "quantize", "rescale"}),
+])
+def test_compiled_programs_carry_named_scopes(deployed_smoke, program, want):
+    """The engine's compiled decode and prefill programs name their ops'
+    layers in the ``op_name`` metadata the profiler reads."""
+    import re
+    cfg, dep = deployed_smoke
+    eng = PagedServingEngine(dep, cfg, backend="oracle", max_batch=2,
+                             page_size=4, n_pages=16, prefill_chunk=4)
+    B = eng.max_batch
+    zi = jnp.zeros((B,), jnp.int32)
+    table = jnp.asarray(eng.sched.table)
+    if program == "decode":
+        lowered = eng._decode.lower(
+            2, eng.params, eng.state, jnp.zeros((B, 1), jnp.int32), zi,
+            table, jnp.ones((B,), bool), zi + 2, zi + 2, zi - 1, eng.rng)
+    else:
+        lowered = eng._prefill_chunk.lower(
+            eng.params, eng.state, jnp.zeros((1, 4), jnp.int32),
+            jnp.int32(0), jnp.int32(0), table[:1])
+    text = lowered.compile().as_text()
+    names = {n for path in re.findall(r'op_name="([^"]*)"', text)
+             for n in path.split("/")}
+    assert want <= names, want - names
+    other = "prefill" if program == "decode" else "decode"
+    assert other not in names
 
 
 def test_local_window_arch_rejected():

@@ -13,6 +13,7 @@ import: only one process may load the TPU library, and the test workers
 must all collect the same tests.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -98,6 +99,28 @@ def test_int8_kv_attention_compiles(one_chip, batch, chunk, seq):
         ((batch, seq, HKV, HD), jnp.int8), ((batch, HKV), jnp.int32),
         ((batch, HKV), jnp.int32), ((batch,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def test_scoped_kernel_keeps_the_name_the_benchmark_matches(one_chip):
+    """Under a ``jax.named_scope`` the kernel's custom call keeps the name
+    the chip benchmark's trace reduction matches
+    (``chipbench.trace.KERNELS``), and carries the scope in its
+    metadata."""
+    from chipbench.trace import KERNELS, op_family
+
+    def f(x, w, e):
+        with jax.named_scope("apsq_gemm"):
+            return apsq_matmul_int8(x, w, e, gs=2)
+
+    text = _compiled_text(f, one_chip, ((1, D), jnp.int8),
+                          ((D, FF), jnp.int8), ((8, FF), jnp.int32))
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls
+    for ln in calls:
+        name = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", ln).group(1)
+        assert KERNELS["apsq_gemm"].match(op_family(name)), name
+        assert re.search(r'op_name="[^"]*/apsq_gemm/', ln), ln
 
 
 @pytest.mark.parametrize("k,n", [(D, 1024), (1024, D)])
