@@ -183,7 +183,7 @@ def check_attention(cfg, seed: int) -> None:
         length = jnp.asarray([CACHE_LEN - 7 * i for i in range(batch)],
                              jnp.int32)
         got, want = (np.asarray(execute_kv_attention(
-            q, kc, vc, ke, ve, length, block_s=PAGE_SIZE, backend=be))
+            q, kc, vc, ke, ve, length, backend=be))
             for be in (PallasBackend(interpret=False), "oracle"))
         np.testing.assert_allclose(got, want, rtol=ATTN_RTOL,
                                    atol=ATTN_ATOL)

@@ -549,8 +549,7 @@ def sharded_int_expert_gemm(mesh, inner, x_codes, w_codes, psum_exps, *,
 
 
 def sharded_kv_attention(mesh, inner, q, k_codes, v_codes, k_exp, v_exp,
-                         length, *, block_s: int,
-                         model_axis: str = "model"):
+                         length, *, model_axis: str = "model"):
     """Head-parallel paged attention: split Hq/Hkv over the model axis.
 
     Attention never mixes heads, so each device attends its own head
@@ -563,13 +562,12 @@ def sharded_kv_attention(mesh, inner, q, k_codes, v_codes, k_exp, v_exp,
     hq = int(q.shape[-2])
     hkv = int(k_codes.shape[2])
     if d <= 1 or hq % d or hkv % d:
-        return inner.kv_attention(q, k_codes, v_codes, k_exp, v_exp, length,
-                                  block_s=block_s)
+        return inner.kv_attention(q, k_codes, v_codes, k_exp, v_exp, length)
     ax = model_axis
     q_spec = (P(None, None, ax, None) if q.ndim == 4 else P(None, ax, None))
 
     def body(ql, kc, vc, ke, ve, ln):
-        return inner.kv_attention(ql, kc, vc, ke, ve, ln, block_s=block_s)
+        return inner.kv_attention(ql, kc, vc, ke, ve, ln)
 
     f = shard_map(body, mesh=mesh,
                   in_specs=(q_spec, P(None, None, ax, None),

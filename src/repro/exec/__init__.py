@@ -18,7 +18,7 @@ from .backends import (
     execute_gemm,
     execute_kv_attention,
     get_backend,
-    kv_block_size,
+    kv_tile,
     quantize_activations,
     register_backend,
 )
@@ -28,6 +28,6 @@ __all__ = [
     "PallasBackend", "ShardedBackend", "available_backends",
     "backend_parity_check",
     "execute_expert_gemm", "execute_gemm", "execute_kv_attention",
-    "get_backend", "kv_block_size", "quantize_activations",
+    "get_backend", "kv_tile", "quantize_activations",
     "register_backend",
 ]

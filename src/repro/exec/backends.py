@@ -80,9 +80,14 @@ class ExecBackend:
 
     def kv_attention(self, q: jax.Array, k_codes: jax.Array,
                      v_codes: jax.Array, k_exp: jax.Array,
-                     v_exp: jax.Array, length: jax.Array, *,
-                     block_s: int) -> jax.Array:
+                     v_exp: jax.Array, length: jax.Array) -> jax.Array:
         raise NotImplementedError
+
+    def kv_tile(self, seq_len: int, head_dim: int, rows: int) -> int:
+        """KV positions per grid step of the attention kernel over
+        ``seq_len`` cache positions with ``rows`` query rows per KV head
+        (``kv_tile``)."""
+        return kv_tile(seq_len, head_dim, rows)
 
     def resolve(self) -> "ExecBackend":
         """The concrete backend that will execute (identity for leaves)."""
@@ -105,8 +110,7 @@ class OracleBackend(ExecBackend):
         return ref.apsq_matmul_ref(x_codes, w_codes, psum_exps,
                                    n_p=n_p, gs=gs)
 
-    def kv_attention(self, q, k_codes, v_codes, k_exp, v_exp, length, *,
-                     block_s):
+    def kv_attention(self, q, k_codes, v_codes, k_exp, v_exp, length):
         from repro.kernels.int8_kv_attention import int8_kv_attention_ref
         return int8_kv_attention_ref(q, k_codes, v_codes, k_exp, v_exp,
                                      length)
@@ -171,22 +175,18 @@ class PallasBackend(ExecBackend):
             x_codes, w_codes, psum_exps, gs=gs, block_m=bm, block_n=bn,
             interpret=self.interpret)
 
-    def kv_attention(self, q, k_codes, v_codes, k_exp, v_exp, length, *,
-                     block_s):
+    def kv_tile(self, seq_len, head_dim, rows):
+        return kv_tile(seq_len, head_dim, rows,
+                       self.block_overrides.get("prefill_attn"))
+
+    def kv_attention(self, q, k_codes, v_codes, k_exp, v_exp, length):
         from repro.kernels.int8_kv_attention import int8_kv_attention
-        if q.ndim == 4:
-            # Chunked prefill: resolve the KV tile through the
-            # ``prefill_attn`` shape class (tuned winner or heuristic;
-            # ``block_overrides`` pins it), snapped to a divisor of S.
-            from repro.kernels import autotune
-            cfg = self.block_overrides.get("prefill_attn")
-            if cfg is None:
-                cfg = autotune.get_block_config(
-                    int(q.shape[1]), int(q.shape[-1]),
-                    int(k_codes.shape[1]), n_p=1, gs=1, attn=True)
-            block_s = kv_block_size(int(k_codes.shape[1]), cfg.block_n)
+        s, hkv, hd = (int(d) for d in k_codes.shape[1:])
+        rows = (int(q.shape[1]) if q.ndim == 4 else 1) * (
+            int(q.shape[-2]) // hkv)
         return int8_kv_attention(q, k_codes, v_codes, k_exp, v_exp, length,
-                                 block_s=block_s, interpret=self.interpret)
+                                 block_s=self.kv_tile(s, hd, rows),
+                                 interpret=self.interpret)
 
 
 class AutoBackend(ExecBackend):
@@ -210,10 +210,12 @@ class AutoBackend(ExecBackend):
         return self.resolve().int_expert_gemm(x_codes, w_codes, psum_exps,
                                               gs=gs)
 
-    def kv_attention(self, q, k_codes, v_codes, k_exp, v_exp, length, *,
-                     block_s):
+    def kv_attention(self, q, k_codes, v_codes, k_exp, v_exp, length):
         return self.resolve().kv_attention(q, k_codes, v_codes, k_exp,
-                                           v_exp, length, block_s=block_s)
+                                           v_exp, length)
+
+    def kv_tile(self, seq_len, head_dim, rows):
+        return self.resolve().kv_tile(seq_len, head_dim, rows)
 
 
 class ShardedBackend(ExecBackend):
@@ -271,15 +273,17 @@ class ShardedBackend(ExecBackend):
             self.mesh, self._leaf(), x_codes, w_codes, psum_exps, gs=gs,
             model_axis=self.model_axis, wire=self.wire)
 
-    def kv_attention(self, q, k_codes, v_codes, k_exp, v_exp, length, *,
-                     block_s):
+    def kv_attention(self, q, k_codes, v_codes, k_exp, v_exp, length):
         if self.mesh is None:
             return self._leaf().kv_attention(q, k_codes, v_codes, k_exp,
-                                             v_exp, length, block_s=block_s)
+                                             v_exp, length)
         from repro.dist.tp import sharded_kv_attention
         return sharded_kv_attention(
             self.mesh, self._leaf(), q, k_codes, v_codes, k_exp, v_exp,
-            length, block_s=block_s, model_axis=self.model_axis)
+            length, model_axis=self.model_axis)
+
+    def kv_tile(self, seq_len, head_dim, rows):
+        return self._leaf().kv_tile(seq_len, head_dim, rows)
 
 
 # ---------------------------------------------------------------------------
@@ -362,25 +366,31 @@ def execute_gemm(dq: DeployedQuantState, x: jax.Array, *,
                 x.dtype).reshape(out_shape)
 
 
-def kv_block_size(seq_len: int, requested: int = 512) -> int:
-    """Largest divisor of ``seq_len`` that is <= ``requested``.
+def kv_tile(seq_len: int, head_dim: int, rows: int,
+            pinned=None) -> int:
+    """The KV tile of one INT8 KV-attention launch: cache positions per
+    grid step, from shapes alone.
 
-    The Pallas KV kernel tiles the cache sequence into ``block_s`` chunks
-    and requires an exact tiling; the oracle ignores it.  Paged caches
-    pass their page size, which divides the gathered sequence by
-    construction.
+    One rule for the decode form (``rows`` = G query rows per KV head),
+    the chunked form (C * G) and the replayed chunk: the ``prefill_attn``
+    shape class resolves a request (``pinned`` -- a ``block_overrides``
+    entry -- or the tuned winner, else the heuristic: up to 512 under
+    the VMEM budget), snapped to the largest legal divisor of
+    ``seq_len`` (``legal_block_s``).  A tile spans many pages, so the
+    grid runs few steps per (slot, head); tiles past a slot's length
+    are neither fetched nor computed.
     """
-    b = max(1, min(requested, seq_len))
-    while seq_len % b:
-        b -= 1
-    return b
+    from repro.kernels import autotune
+    from repro.kernels.int8_kv_attention import legal_block_s
+    cfg = pinned or autotune.get_block_config(rows, head_dim, seq_len,
+                                              n_p=1, gs=1, attn=True)
+    return legal_block_s(seq_len, cfg.block_n)
 
 
 @jax.named_scope("kv_attention")
 def execute_kv_attention(q: jax.Array, k_codes: jax.Array,
                          v_codes: jax.Array, k_exp: jax.Array,
                          v_exp: jax.Array, length: jax.Array, *,
-                         block_s: int | None = None,
                          backend=None) -> jax.Array:
     """Attention over an INT8 KV cache through the backend registry.
 
@@ -391,18 +401,15 @@ def execute_kv_attention(q: jax.Array, k_codes: jax.Array,
     cache prefix.  Returns output matching q's rank, in q's dtype.  This
     is the second op family beside ``execute_gemm``: the ``oracle``
     backend runs the shape-polymorphic jnp reference, the ``pallas``
-    backend the flash-decode TPU kernel; chunked
-    launches resolve their KV tile via the ``prefill_attn`` autotune
-    shape class.
+    backend the flash-decode TPU kernel, whose KV tile follows from the
+    shapes (``kv_tile``) in both forms.
     """
     backend = get_backend(backend).resolve()
-    s = int(k_codes.shape[1])
-    block_s = kv_block_size(s, block_s if block_s is not None else 512)
     length = jnp.broadcast_to(jnp.asarray(length, jnp.int32),
                               (k_codes.shape[0],))
     return backend.kv_attention(
         q, k_codes, v_codes, k_exp.astype(jnp.int32),
-        v_exp.astype(jnp.int32), length, block_s=block_s)
+        v_exp.astype(jnp.int32), length)
 
 
 def backend_parity_check(dq: DeployedQuantState, x: jax.Array, *,

@@ -32,10 +32,11 @@ Shape classes
 ``prefill``    M > 32 — batched prefill / QAT forward.
 ``expert``     MoE expert-bank GEMMs (per-expert M = dispatch capacity),
                executed by the fused expert-grid kernel.
-``prefill_attn``  chunked-prefill KV attention (``int8_kv_attention``
-               with a [chunk] query block): m = chunk rows, k = head dim,
-               n = gathered KV sequence; ``block_n`` is the kernel's
-               ``block_s`` KV tile (snapped to a divisor of S at launch).
+``prefill_attn``  INT8 KV attention (``int8_kv_attention``), decode
+               rows and prefill chunks alike: m = query rows per KV
+               head, k = head dim, n = gathered KV sequence; ``block_n``
+               is the kernel's ``block_s`` KV tile (snapped to a legal
+               divisor of S at launch, ``repro.exec.kv_tile``).
 
 Cache
 -----
@@ -162,7 +163,7 @@ def heuristic_config(cls: str, m: int, k: int, n: int, *, n_p: int,
     elif cls == "expert":
         bm, bn = _fit_block(m, 128, 8), _fit_block(n, 256, 128)
     elif cls == "prefill_attn":
-        # m = chunk rows (all resident in the q tile), n = KV sequence;
+        # m = query rows (all resident in the q tile), n = KV sequence;
         # block_n is the flash-decode block_s KV tile.
         bm, bn = _fit_block(m, 32, 8), _fit_block(n, 512, 128)
     else:  # prefill
@@ -324,7 +325,7 @@ def _default_measure_attn(cfg: BlockConfig, m: int, k: int, n: int, *,
     ``block_s`` KV tile (snapped to a divisor of S, as at serving time)."""
     import jax.numpy as jnp
 
-    from .int8_kv_attention import int8_kv_attention
+    from .int8_kv_attention import int8_kv_attention, legal_block_s
 
     B, Hkv, G = 1, 4, 2
     key = jax.random.PRNGKey(0)
@@ -334,9 +335,7 @@ def _default_measure_attn(cfg: BlockConfig, m: int, k: int, n: int, *,
     vc = jax.random.randint(jax.random.fold_in(key, 2), (B, n, Hkv, k),
                             -128, 128, jnp.int8)
     exps = jnp.full((B, Hkv), -7, jnp.int32)
-    block_s = max(1, min(cfg.block_n, n))
-    while n % block_s:
-        block_s -= 1
+    block_s = legal_block_s(n, cfg.block_n)
     f = lambda: int8_kv_attention(q, kc, vc, exps, exps, n,
                                   block_s=block_s, interpret=interpret)
     jax.block_until_ready(f())  # compile + warmup

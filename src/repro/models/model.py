@@ -830,7 +830,9 @@ def decode_horizon_paged(
 
     Step ``t`` masks a slot exactly the way the engine's single-step path
     masks non-decoding slots — zeroed table row (writes land on the null
-    page), per-slot leaves reverted, fed token 0, position frozen — so
+    page), per-slot leaves reverted, fed token 0 at position 0 (so its
+    attention reads one cache tile, not its stale length), position
+    frozen in the carry — so
     ``horizon`` fused steps are token- AND KV-bit-identical to ``horizon``
     single ``decode_step_paged`` calls with host-side masking, including a
     slot that hits EOS or exhausts its token budget mid-horizon.
@@ -850,7 +852,8 @@ def decode_horizon_paged(
         st, tok, ps, act, bud, rem, key = carry
         on = act & (bud > 0)
         tbl = jnp.where(on[:, None], page_table, NULL_PAGE)
-        lg, st2 = decode_step_paged(p, cfg, st, tok, ps, tbl,
+        lg, st2 = decode_step_paged(p, cfg, st, tok,
+                                    jnp.where(on, ps, 0), tbl,
                                     mesh=mesh, backend=backend)
         st2 = jax.tree.map(lambda o, n, ax: _keep_slots(o, n, ax, on),
                            st, st2, axes)

@@ -438,7 +438,6 @@ class PagedServingEngine:
     with the decode batch), decode grows each slot's page list on demand,
     and a dry pool preempts the latest-admitted request (requeued at the
     front; resume re-prefills prompt + generated and is bit-identical).
-    ``page_size`` doubles as the attention kernel's ``block_s`` tile.
 
     Knobs (see the module docstring for when to turn them):
       * ``prefill_chunk``        — max tokens per prefill forward; the
@@ -540,6 +539,13 @@ class PagedServingEngine:
         # pre-preemption output).  While a slot is here, ``pos[slot]`` is
         # its prefilled_len — the last completed chunk boundary.
         self._mid_prefill: dict[int, np.ndarray] = {}
+        # The attention kernel's KV tile over a slot's gathered pages, as
+        # the decode launch resolves it (``kv_tile``): the tile counters
+        # below replay the kernel's skip of tiles past each slot's length.
+        seq = self.sched.table.shape[1] * page_size
+        self._kv_tile = self.backend.kv_tile(
+            seq, cfg.hd, cfg.n_heads // cfg.n_kv_heads)
+        self._kv_tiles = seq // self._kv_tile
         self.reset_counters()
         self._decode = jax.jit(self._decode_impl, static_argnums=(0,))
         self._prefill_chunk = jax.jit(self._prefill_chunk_impl)
@@ -552,6 +558,13 @@ class PagedServingEngine:
         self.decode_dispatches = 0   # fused decode launches
         self.decode_device_steps = 0  # scan steps across those launches
         self.horizon_hist: dict[int, int] = {}  # scan length -> launches
+        # Decode attention's KV tiles, per slot and scan step (a tile
+        # stands for that tile of every KV head in every attention layer):
+        # those the grid launches, and those holding a live position,
+        # which the kernel fetches and computes.  A slot not decoding at a
+        # step attends over length 1.
+        self.kv_tiles_launched = 0
+        self.kv_tiles_live = 0
 
     @classmethod
     def from_exported(cls, params, cfg: ModelConfig, *, policy=None, **kw):
@@ -821,6 +834,11 @@ class PagedServingEngine:
             self.decode_dispatches += 1
             self.decode_device_steps += h
             self.horizon_hist[h] = self.horizon_hist.get(h, 0) + 1
+            # Step t attends over pos + (tokens emitted before t) + 1.
+            length = np.where(em, self.pos[:, None] + np.cumsum(em, 1)
+                              - em + 1, 1)
+            self.kv_tiles_launched += em.size * self._kv_tiles
+            self.kv_tiles_live += int(np.sum(-(-length // self._kv_tile)))
             for s in active:
                 r = self.sched.slots[s]
                 for t in range(h):

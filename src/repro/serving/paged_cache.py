@@ -23,7 +23,8 @@ tests pin down.
 The read path dispatches through the ``repro.exec`` backend registry
 (``execute_kv_attention``): the gathered page view is exactly the dense
 [B, S, Hkv, hd] layout the ``kernels/int8_kv_attention`` flash-decode
-kernel consumes, with ``block_s = page_size``.
+kernel consumes; the kernel tiles it by a KV tile that follows from the
+shapes alone, spanning many pages.
 """
 from __future__ import annotations
 
@@ -150,9 +151,9 @@ def paged_update_and_attend(cache: dict, q: jax.Array, k_new: jax.Array,
     pos: [B] int32 (position being written); page_table: [B, n_max].
 
     Returns (out [B, Hq, hd], new_cache).  The attention itself runs
-    through ``repro.exec.execute_kv_attention`` with ``block_s`` = the
-    page size, so the serving read path is the registered op family
-    (the jnp oracle or the Pallas flash-decode kernel, per ``backend``).
+    through ``repro.exec.execute_kv_attention``, so the serving read path
+    is the registered op family (the jnp oracle or the Pallas
+    flash-decode kernel, per ``backend``).
     """
     from repro.exec import execute_kv_attention
     pos = jnp.asarray(pos, jnp.int32)
@@ -164,7 +165,7 @@ def paged_update_and_attend(cache: dict, q: jax.Array, k_new: jax.Array,
     k_seq = gk.reshape(b, n_max * page_size, *gk.shape[3:])
     v_seq = gv.reshape(b, n_max * page_size, *gv.shape[3:])
     out = execute_kv_attention(q, k_seq, v_seq, k_exp, v_exp, pos + 1,
-                               block_s=page_size, backend=backend)
+                               backend=backend)
     return out, {"k_pages": k_pages, "v_pages": v_pages,
                  "k_exp": k_exp, "v_exp": v_exp}
 
@@ -213,8 +214,7 @@ def paged_prefill_chunk_update_and_attend(cache: dict, q: jax.Array,
         k_seq = gk.reshape(b, seq, *gk.shape[3:])
         v_seq = gv.reshape(b, seq, *gv.shape[3:])
         return execute_kv_attention(q, k_seq, v_seq, k_exp, v_exp,
-                                    pos + chunk, block_s=page_size,
-                                    backend=backend)
+                                    pos + chunk, backend=backend)
 
     def attend_replay(_):
         def step(carry, xs):
@@ -225,7 +225,7 @@ def paged_prefill_chunk_update_and_attend(cache: dict, q: jax.Array,
             out_t = execute_kv_attention(
                 qt, cgk.reshape(b, seq, *cgk.shape[3:]),
                 cgv.reshape(b, seq, *cgv.shape[3:]), cke, cve,
-                pos + t + 1, block_s=page_size, backend=backend)
+                pos + t + 1, backend=backend)
             return (cgk, cke, cgv, cve), out_t
 
         xs = (jnp.moveaxis(q, 1, 0), jnp.moveaxis(k_new, 1, 0),

@@ -267,8 +267,10 @@ def test_engine_auto_backend_matches_oracle_on_cpu():
 
 def test_kv_attention_op_family_backend_parity():
     """execute_kv_attention dispatches per backend; oracle == pallas ==
-    auto (auto resolves to oracle on CPU) within interpret tolerance."""
+    auto (auto resolves to oracle on CPU) within interpret tolerance.
+    The kernel's tile is pinned below S, so it runs two KV tiles."""
     from repro.exec import execute_kv_attention
+    from repro.kernels.autotune import BlockConfig
     from repro.kernels.int8_kv_attention import quantize_kv_po2
 
     key = jax.random.PRNGKey(9)
@@ -278,9 +280,11 @@ def test_kv_attention_op_family_backend_parity():
     kc, ke = quantize_kv_po2(k)
     vc, ve = quantize_kv_po2(v)
     L = jnp.asarray([17, 64], jnp.int32)
+    pallas = PallasBackend(interpret=True, block_overrides={
+        "prefill_attn": BlockConfig(8, 32)})
     outs = {getattr(be, "name", be): execute_kv_attention(
-                q, kc, vc, ke, ve, L, block_s=32, backend=be)
-            for be in ("oracle", PALLAS, "auto")}
+                q, kc, vc, ke, ve, L, backend=be)
+            for be in ("oracle", pallas, "auto")}
     np.testing.assert_allclose(np.asarray(outs["pallas"]),
                                np.asarray(outs["oracle"]),
                                rtol=2e-5, atol=2e-6)
@@ -289,14 +293,21 @@ def test_kv_attention_op_family_backend_parity():
 
 
 def test_kv_attention_scalar_length_and_block_rounding():
-    """Scalar lengths broadcast; a non-dividing block_s is rounded down
-    to a divisor of S instead of erroring (kv_block_size)."""
-    from repro.exec import execute_kv_attention, kv_block_size
+    """Scalar lengths broadcast; the KV tile follows from shapes, and a
+    request that does not divide S is rounded down to a legal divisor of
+    S instead of erroring (kv_tile)."""
+    from repro.exec import execute_kv_attention, kv_tile
+    from repro.kernels.autotune import BlockConfig
     from repro.kernels.int8_kv_attention import quantize_kv_po2
 
-    assert kv_block_size(96, 512) == 96
-    assert kv_block_size(96, 64) == 48
-    assert kv_block_size(7, 4) == 1
+    assert kv_tile(96, 8, 1) == 96
+    assert kv_tile(640, 128, 1) == kv_tile(640, 128, 256) == 320
+    assert kv_tile(96, 8, 1, BlockConfig(8, 64)) == 48
+    assert kv_tile(48, 8, 1, BlockConfig(8, 20)) == 16
+    assert kv_tile(7, 8, 1, BlockConfig(8, 4)) == 7
+    pinned = PallasBackend(interpret=True, block_overrides={
+        "prefill_attn": BlockConfig(8, 20)})
+    assert pinned.kv_tile(48, 8, 1) == 16
 
     key = jax.random.PRNGKey(10)
     q = jax.random.normal(key, (1, 4, 8))
@@ -304,8 +315,7 @@ def test_kv_attention_scalar_length_and_block_rounding():
     v = jax.random.normal(jax.random.fold_in(key, 2), (1, 48, 2, 8))
     kc, ke = quantize_kv_po2(k)
     vc, ve = quantize_kv_po2(v)
-    a = execute_kv_attention(q, kc, vc, ke, ve, 20, block_s=20,
-                             backend=PALLAS)
+    a = execute_kv_attention(q, kc, vc, ke, ve, 20, backend=pinned)
     b = execute_kv_attention(q, kc, vc, ke, ve,
                              jnp.asarray([20], jnp.int32), backend="oracle")
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),

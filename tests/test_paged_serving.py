@@ -507,7 +507,8 @@ def _ref_single_steps(cfg, p, state, tokens, pos, table, h, *, active,
     for _ in range(h):
         on = act & (bud > 0)
         tbl = jnp.where(on[:, None], table, NULL_PAGE)
-        lg, st2 = decode_step_paged(p, cfg, state, tokens, pos, tbl,
+        lg, st2 = decode_step_paged(p, cfg, state, tokens,
+                                    jnp.where(on, pos, 0), tbl,
                                     backend=backend)
 
         def keep(old, new, ax):
@@ -664,3 +665,27 @@ def test_horizon_one_bit_identical_to_fused_path(params):
     # 1 token from prefill logits + 5 decode tokens, one launch each.
     assert eng.decode_dispatches == eng.decode_device_steps == 5
     assert set(eng.horizon_hist) == {1}
+
+
+@pytest.mark.parametrize("h,launched,live", [
+    # 7 decode steps, 2 slots, 2 tiles of 320 over 40 pages of 16: slot 0
+    # attends over 317..323 positions (one tile up to 320, then two);
+    # the empty slot over one position.
+    (1, 7 * 2 * 2, 4 * 1 + 3 * 2 + 7),
+    # One fused dispatch of 8 steps: slot 0 is done after its 7th.
+    (8, 8 * 2 * 2, 4 * 1 + 3 * 2 + 1 + 8),
+])
+def test_kv_tile_counters(params, h, launched, live):
+    """``kv_tiles_launched`` / ``kv_tiles_live`` replay the attention
+    grid's KV tiles per slot and decode step, and the kernel's skip of
+    those past each slot's length; ``reset_counters`` zeroes them."""
+    eng = _engine(params, max_batch=2, page_size=16, n_pages=48,
+                  max_pages_per_slot=40, prefill_chunk=16,
+                  decode_horizon=h)
+    assert eng._kv_tile == 320
+    done = eng.run([Request(uid=0, tokens=_prompt(316), max_new_tokens=8)])
+    assert len(done[0].out) == 8 and eng.decode_device_steps == 8 - (h == 1)
+    assert (eng.kv_tiles_launched, eng.kv_tiles_live) == (launched, live)
+    assert eng.kv_tiles_live <= eng.kv_tiles_launched
+    eng.reset_counters()
+    assert eng.kv_tiles_launched == eng.kv_tiles_live == 0

@@ -6,7 +6,8 @@ described ``v5e:2x2`` topology and compiles it with the TPU compiler
 shapes off the (8, 128) tiling, too much VMEM.  Shapes are those of
 tinyllama-1.1b (d_model 2048, 32 query / 4 KV heads of 64, d_ff 5632,
 vocab 32000) served by ``PagedServingEngine`` (page size 16, prefill
-chunk 16), and olmoe-1b-7b's expert banks (64 experts, d_ff 1024).
+chunk 16), olmoe-1b-7b's expert banks (64 experts, d_ff 1024), and the
+KV attention of the chip benchmark's deepseek-7b and chatglm3-6b.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, and the test workers
@@ -25,7 +26,6 @@ from repro.kernels.apsq_matmul import (
     apsq_matmul_int8,
     baseline_matmul_int8,
 )
-from repro.kernels.int8_kv_attention import int8_kv_attention
 
 D, HQ, HKV, HD, FF, VOCAB = 2048, 32, 4, 64, 5632, 32000
 PAGE, CHUNK = 16, 16
@@ -84,20 +84,32 @@ def test_w8a8_baseline_kernel_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("batch,chunk,seq", [
-    (8, 0, 1024),                  # decode: 8 slots of 64 pages
-    (4, 0, 16),                    # decode over a single page
-    (1, CHUNK, 1024),              # chunked prefill, one query block
-    (1, 8, 64),
+@pytest.mark.parametrize("batch,chunk,seq,hq,hkv,hd", [
+    pytest.param(8, 0, 1024, HQ, HKV, HD,
+                 id="8-0-1024"),   # decode: 8 slots of 64 pages
+    pytest.param(4, 0, 16, HQ, HKV, HD,
+                 id="4-0-16"),     # decode over a single page
+    pytest.param(1, CHUNK, 1024, HQ, HKV, HD,
+                 id="1-16-1024"),  # chunked prefill, one query block
+    pytest.param(1, 8, 64, HQ, HKV, HD, id="1-8-64"),
+    # The chip benchmark's cells: 16 slots of 40 pages of 16.
+    pytest.param(16, 0, 640, 32, 32, 128, id="deepseek-7b-decode"),
+    pytest.param(16, 0, 640, 32, 2, 128, id="chatglm3-6b-decode"),
+    pytest.param(1, CHUNK, 640, 32, 32, 128, id="deepseek-7b-chunk"),
+    pytest.param(1, CHUNK, 640, 32, 2, 128, id="chatglm3-6b-chunk"),
 ])
-def test_int8_kv_attention_compiles(one_chip, batch, chunk, seq):
-    q = (batch, HQ, HD) if chunk == 0 else (batch, chunk, HQ, HD)
-    text = _compiled_text(
-        lambda q, k, v, ke, ve, n: int8_kv_attention(
-            q, k, v, ke, ve, n, block_s=PAGE), one_chip,
-        (q, jnp.bfloat16), ((batch, seq, HKV, HD), jnp.int8),
-        ((batch, seq, HKV, HD), jnp.int8), ((batch, HKV), jnp.int32),
-        ((batch, HKV), jnp.int32), ((batch,), jnp.int32))
+def test_int8_kv_attention_compiles(one_chip, batch, chunk, seq, hq, hkv,
+                                    hd):
+    """With the KV tile the Pallas backend resolves from the shapes
+    (many pages, several heads a step, the scalar-prefetched lengths)."""
+    from repro.exec import PallasBackend
+    q = (batch, hq, hd) if chunk == 0 else (batch, chunk, hq, hd)
+    text = _compiled_text(PallasBackend().kv_attention, one_chip,
+                          (q, jnp.bfloat16),
+                          ((batch, seq, hkv, hd), jnp.int8),
+                          ((batch, seq, hkv, hd), jnp.int8),
+                          ((batch, hkv), jnp.int32),
+                          ((batch, hkv), jnp.int32), ((batch,), jnp.int32))
     assert "tpu_custom_call" in text
 
 
@@ -121,6 +133,32 @@ def test_scoped_kernel_keeps_the_name_the_benchmark_matches(one_chip):
         name = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", ln).group(1)
         assert KERNELS["apsq_gemm"].match(op_family(name)), name
         assert re.search(r'op_name="[^"]*/apsq_gemm/', ln), ln
+
+
+def test_scoped_kv_kernel_keeps_the_name_the_benchmark_matches(one_chip):
+    """The INT8 KV-attention kernel, launched as the paged decode launches
+    it, keeps the op name ``chipbench.trace.KERNELS["kv_attn"]`` matches,
+    under its ``kv_attention`` scope."""
+    from chipbench.trace import KERNELS, op_family
+    from repro.exec import PallasBackend, execute_kv_attention
+
+    def f(q, k, v, ke, ve, n):
+        return execute_kv_attention(q, k, v, ke, ve, n,
+                                    backend=PallasBackend())
+
+    b, s = 16, 40 * PAGE
+    text = _compiled_text(f, one_chip, ((b, HQ, HD), jnp.float32),
+                          ((b, s, HKV, HD), jnp.int8),
+                          ((b, s, HKV, HD), jnp.int8),
+                          ((b, HKV), jnp.int32), ((b, HKV), jnp.int32),
+                          ((b,), jnp.int32))
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls
+    for ln in calls:
+        name = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", ln).group(1)
+        assert KERNELS["kv_attn"].match(op_family(name)), name
+        assert re.search(r'op_name="[^"]*kv_attention/', ln), ln
 
 
 @pytest.mark.parametrize("k,n", [(D, 1024), (1024, D)])
