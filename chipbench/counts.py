@@ -1,6 +1,8 @@
-"""Operations and bytes of the served model's calls, from shapes alone.
+"""Operations and bytes of one kernel call, from shapes alone.
 
-The yardstick for the roofline shares and ``step_mfu``.  Conventions:
+The yardstick for the roofline shares and ``step_mfu``: a family's
+``call_costs`` (``chipbench/families``) sums these over the calls of one
+engine dispatch.  Conventions:
 
 * a GEMM of [M, K] x [K, N] is 2 M K N operations and moves its INT8
   weights once (K N bytes), its INT8 activation codes (M K), its INT32
@@ -14,13 +16,6 @@ The yardstick for the roofline shares and ``step_mfu``.  Conventions:
   gains share instead of losing it.
 """
 from __future__ import annotations
-
-def linear_shapes(dims: dict) -> list:
-    """[(K, N)] of one layer's seven projections."""
-    d, hq, hkv, hd, f = (dims["d_model"], dims["n_heads"],
-                         dims["n_kv_heads"], dims["hd"], dims["d_ff"])
-    return [(d, hq * hd), (d, hkv * hd), (d, hkv * hd), (hq * hd, d),
-            (d, f), (d, f), (f, d)]
 
 
 def gemm_ops(m: int, k: int, n: int) -> float:
@@ -44,62 +39,7 @@ def attn_bytes(dims: dict, kv_tokens: int, q_rows: int) -> float:
     return float(2 * kv_tokens * hkv * hd + 2 * 4 * q_rows * hq * hd)
 
 
-def gemm_params(dims: dict) -> float:
-    """Weights of every GEMM a token passes through: the layers' seven
-    projections and the LM head."""
-    per_layer = sum(k * n for k, n in linear_shapes(dims))
-    return float(dims["n_layers"] * per_layer
-                 + dims["d_model"] * dims["vocab"])
-
-
-def token_ops(dims: dict, ctx: int) -> float:
-    """Model operations of one processed token that sees ``ctx``
-    positions: 2 x GEMM weights + 4 ctx Hq hd per attention layer."""
-    return (2.0 * gemm_params(dims)
-            + dims["n_layers"] * attn_ops(dims, ctx))
-
-
 def roofline_seconds(ops: float, byt: float, ops_peak: float,
                      bw_peak: float) -> float:
     """Least time the chip could take: the larger of the two bounds."""
     return max(ops / ops_peak, byt / bw_peak)
-
-
-def call_costs(dims: dict, call: dict, peak: dict) -> dict:
-    """Least device seconds and model operations of one engine dispatch.
-
-    ``call`` is a dispatch record: ``{"kind": "decode", "rows": B,
-    "steps": [[ctx of each emitted token], ...]}`` (one list per scan
-    step; a token's ctx is the positions its query sees) or ``{"kind":
-    "prefill", "start": p, "chunk": c}``.  Each GEMM call and each
-    attention call is bounded on its own (int8 peak for the APSQ GEMMs,
-    bf16 peak for the float32 attention, HBM bandwidth for both), and the
-    bounds are summed per kernel family."""
-    L = dims["n_layers"]
-    i8, bf, bw = peak["int8_ops"], peak["bf16_flops"], peak["hbm_bytes_s"]
-
-    def gemm_roof(m):
-        return sum(roofline_seconds(gemm_ops(m, k, n),
-                                    gemm_bytes(m, k, n, dims["n_p"]), i8, bw)
-                   for k, n in linear_shapes(dims))
-
-    out = {"gemm_s": 0.0, "attn_s": 0.0, "model_ops": 0.0, "tokens": 0}
-    if call["kind"] == "decode":
-        for ctxs in call["steps"]:
-            out["gemm_s"] += L * gemm_roof(call["rows"])
-            live = sum(ctxs)
-            out["attn_s"] += L * roofline_seconds(
-                attn_ops(dims, live), attn_bytes(dims, live, len(ctxs)),
-                bf, bw)
-            out["model_ops"] += sum(token_ops(dims, c) for c in ctxs)
-            out["tokens"] += len(ctxs)
-    else:
-        p, c = call["start"], call["chunk"]
-        out["gemm_s"] += L * gemm_roof(c)
-        live = sum(p + t + 1 for t in range(c))
-        out["attn_s"] += L * roofline_seconds(
-            attn_ops(dims, live), attn_bytes(dims, p + c, c), bf, bw)
-        out["model_ops"] += sum(token_ops(dims, p + t + 1)
-                                for t in range(c))
-        out["tokens"] += c
-    return out

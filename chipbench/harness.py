@@ -2,9 +2,12 @@
 
 Everything a cell needs is found by name: ``BENCHMARK.json`` names the
 cell's configuration, traffic mix and metrics; the configuration lives
-in ``configs/<config>.json``, the mix in ``traffic/<mix>.json``, the
-check's limits in ``limits/<cell>.json``, each metric's reader in
-``metrics/<metric>.py`` and the chip's peaks in ``peaks.json``.
+in ``configs/<config>.json``, its model family (the file's ``family``,
+else ``dense``: sizes, deployed tree and weight rules, plain reference
+and op counts) in ``families/<family>.py``, the mix in
+``traffic/<mix>.json``, the check's limits in ``limits/<cell>.json``,
+each metric's reader in ``metrics/<metric>.py`` and the chip's peaks in
+``peaks.json``.
 
 The served path is the program's own: ``PagedServingEngine`` over the
 deployed INT8 tree, driven through ``add_request`` and ``step`` by a
@@ -42,6 +45,13 @@ def load_json(path: Path) -> dict:
         return json.load(f)
 
 
+def load_module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 class Bench:
     """``BENCHMARK.json`` and the files it names."""
 
@@ -72,12 +82,22 @@ class Bench:
                 if "workloads" not in m or cell in m["workloads"]]
 
     def reader(self, metric: str):
-        path = self.dir / "metrics" / f"{metric}.py"
-        spec = importlib.util.spec_from_file_location(
-            f"chipbench_metric_{metric.replace('.', '_')}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return load_module(self.dir / "metrics" / f"{metric}.py",
+                           f"chipbench_metric_{metric.replace('.', '_')}"
+                           ).read
+
+    def family(self, conf: dict):
+        """The module of a configuration's model family
+        (``chipbench/families/__init__.py`` says what it gives); unknown
+        is an error."""
+        name = conf.get("family", "dense")
+        fams = self.dir / "families"
+        known = sorted(p.stem for p in fams.glob("[!_]*.py"))
+        if name not in known:
+            raise KeyError(f"model family {name!r} has no file in "
+                           f"{fams.name}/ (known: {known})")
+        return load_module(fams / f"{name}.py",
+                           f"chipbench_family_{name.replace('.', '_')}")
 
 
 def peaks_for(kind: str, path: Path = HERE / "peaks.json") -> dict:
@@ -112,28 +132,6 @@ class CompileStats:
     def snapshot(self) -> tuple:
         return (self.seconds, self.events["compiles"],
                 self.events["cache_hits"], self.events["cache_misses"])
-
-
-def model_dims(conf: dict) -> dict:
-    """Sizes as the benchmark states them (the configuration file)."""
-    m = conf["model"]
-    return {"n_layers": m["n_layers"], "d_model": m["d_model"],
-            "n_heads": m["n_heads"], "n_kv_heads": m["n_kv_heads"],
-            "hd": m["head_dim"], "d_ff": m["d_ff"], "vocab": m["vocab"],
-            "rope_fraction": m["rope_fraction"],
-            "rope_theta": m["rope_theta"], "eps": m["norm_eps"],
-            "n_p": conf["quant"]["n_p"]}
-
-
-def check_dims(dims: dict, cfg) -> None:
-    """The program's config must be the one the file states."""
-    got = {"n_layers": cfg.n_layers, "d_model": cfg.d_model,
-           "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
-           "hd": cfg.hd, "d_ff": cfg.d_ff, "vocab": cfg.vocab,
-           "rope_fraction": cfg.rope_fraction, "rope_theta": cfg.rope_theta}
-    bad = {k: (v, dims[k]) for k, v in got.items() if v != dims[k]}
-    if bad:
-        raise ValueError(f"program config differs from the file: {bad}")
 
 
 # ---------------------------------------------------------------------------
@@ -291,8 +289,8 @@ class Dispatches:
         self._enqueue()
 
     def records(self):
-        """Dispatch records as ``chipbench.counts.call_costs`` takes them,
-        or None where they cannot be trusted."""
+        """Dispatch records as a family's ``call_costs`` takes them, or
+        None where they cannot be trusted."""
         if self.bad:
             log(f"dispatch records left out: {sorted(set(self.bad))}")
             return None
@@ -345,6 +343,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     bench = Bench(root)
     cell = bench.cell(workload)
     conf = bench.config(cell["config"])
+    fam = bench.family(conf)
     mix = bench.traffic(cell["traffic"])
     limits = bench.limits(workload)
     dev = jax.devices()[0]
@@ -363,10 +362,11 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     # -- weights -----------------------------------------------------------
     t0 = time.perf_counter()
     cfg = weights.model_config(conf)
-    dims = model_dims(conf)
-    check_dims(dims, cfg)
-    abstract = weights.deployed_shapes(cfg)
-    params = weights.make_params(seed, cfg, abstract)
+    dims = fam.dims(conf)
+    fam.check(dims, cfg)
+    abstract = fam.deployed_shapes(cfg)
+    params = weights.make_params(seed, abstract, fam.mean_abs_in,
+                                 fam.float_init)
     jax.block_until_ready(params)
     split["weights_s"] = time.perf_counter() - t0
 
@@ -480,6 +480,8 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
             raise SystemExit("the traced window ran no Mosaic kernel")
         ctx["trace"] = red_s
         ctx["calls"] = calls
+        ctx["call_costs"] = (None if calls is None or peak is None else
+                             [fam.call_costs(dims, c, peak) for c in calls])
         ctx["trace_counters"] = {k: red["c1"][k] - red["c0"][k]
                                  for k in red["c0"]}
         result_device["busy_s"] = red_s["busy_s"]
@@ -505,7 +507,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
                + traffic.max_len(mix["output"]))
     del eng, params, clients, disp
     gc.collect()
-    check = check_served(seed, cfg, dims, abstract, done, mix, limits,
+    check = check_served(seed, fam, cfg, dims, abstract, done, mix, limits,
                          max_len, control, dump)
     correct = all(v["value"] is not None and v["value"] <= v["limit"]
                   for v in check.values())
@@ -557,12 +559,12 @@ def gap_numbers(per: list) -> dict:
             "max_logit_gap": float(allg.max())}
 
 
-def check_served(seed, cfg, dims, abstract, done, mix, limits, max_len,
-                 control=False, dump=None):
+def check_served(seed, fam, cfg, dims, abstract, done, mix, limits,
+                 max_len, control=False, dump=None):
     """Compare the served tokens of a sample of finished requests with
-    the plain reference: per token, the gap by which its reference logit
-    lies below the reference's best.  The numbers named in the limits
-    file's ``compare`` are held to their limits.
+    the family's plain reference: per token, the gap by which its
+    reference logit lies below the reference's best.  The numbers named
+    in the limits file's ``compare`` are held to their limits.
 
     With ``control`` the reference at the lower precision
     (``control_bits``) takes the program's place: at each position of
@@ -577,17 +579,8 @@ def check_served(seed, cfg, dims, abstract, done, mix, limits, max_len,
     if not sample:
         return {k: {"value": None, "limit": v, "requests": 0, "tokens": 0}
                 for k, v in limits["compare"].items()}
-    key = weights.seed_key(seed)
-    draw = weights.unit_codes_fn(abstract)
-    ref = reference.Reference(
-        {"n_units": cfg.n_units, "n_heads": dims["n_heads"],
-         "n_kv_heads": dims["n_kv_heads"], "hd": dims["hd"],
-         "rope_fraction": dims["rope_fraction"],
-         "rope_theta": dims["rope_theta"], "eps": dims["eps"]},
-        weights.linear_meta(abstract), lambda u: draw(key, u),
-        weights.float_leaf(key, abstract, "embed", "table"),
-        weights.float_leaf(key, abstract, "head", "w"),
-        t_pad=max_len)
+    ref = fam.reference(weights.seed_key(seed), cfg, dims, abstract,
+                        max_len)
     seqs, rows, toks = [], [], []
     for r in sample:
         out = np.asarray(r["req"].out, np.int32)

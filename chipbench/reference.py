@@ -3,15 +3,12 @@
 It imports nothing of the program.  It takes the configuration's sizes,
 the static exponents of each linear, and draws the weights itself from
 the seed through ``chipbench.weights`` (the benchmark's own generator),
-one layer at a time, so it fits beside nothing else on the chip.
+one layer at a time, so it fits beside nothing else on the chip.  What a
+layer holds is its family's (``chipbench/families``); the pieces here are
+shared by every family.
 
 Semantics, as the configuration states them:
 
-* decoder-only, pre-norm: RMSNorm (eps 1e-6, gain) -> attention ->
-  residual; RMSNorm -> SwiGLU MLP -> residual; final RMSNorm; untied LM
-  head as a float GEMM.  Activations are bfloat16 between ops; norms,
-  rotary embedding and attention compute in float32 and round once, the
-  SwiGLU product is bfloat16 arithmetic.
 * every projection is W8A8 with APSQ partial sums (Algorithm 1): the
   input is quantized to INT8 at 2^ax (round half to even, clip), the
   K axis is cut into n_p tiles whose INT32 products are stored as INT8
@@ -24,6 +21,7 @@ Semantics, as the configuration states them:
   each new token (amax / 127); stored codes are re-quantized to a grown
   exponent by a shift with round half up.  Query t attends over the
   codes as they stand after token t is written, in float32.
+* the final RMSNorm and the untied LM head, a float32 GEMM.
 
 ``bits`` lowers the integer widths (the control): activation codes,
 partial-sum codes and KV codes at ``bits`` instead of 8, over the same
@@ -185,29 +183,6 @@ def kv_attention(q, k, v, bits: int = 8):
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("dims", "meta", "bits"))
-def layer(x, codes, *, dims, meta, bits):
-    """One decoder layer.  ``meta`` is a tuple of (name, exponents)."""
-    d = dict(dims)
-    m = {k: dict(v) for k, v in meta}
-    T = x.shape[0]
-    hq, hkv, hd = d["n_heads"], d["n_kv_heads"], d["hd"]
-    pos = jnp.arange(T)
-    h = rmsnorm(x, d["eps"])
-    q = qgemm(h, codes["0.mix.wq"], m["0.mix.wq"], bits).reshape(T, hq, hd)
-    k = qgemm(h, codes["0.mix.wk"], m["0.mix.wk"], bits).reshape(T, hkv, hd)
-    v = qgemm(h, codes["0.mix.wv"], m["0.mix.wv"], bits).reshape(T, hkv, hd)
-    q = rope(q, pos, d["rope_fraction"], d["rope_theta"])
-    k = rope(k, pos, d["rope_fraction"], d["rope_theta"])
-    a = kv_attention(q, k, v, bits).reshape(T, hq * hd)
-    x = x + qgemm(a, codes["0.mix.wo"], m["0.mix.wo"], bits)
-    h = rmsnorm(x, d["eps"])
-    gate = qgemm(h, codes["0.ffn.wg"], m["0.ffn.wg"], bits)
-    up = qgemm(h, codes["0.ffn.wi"], m["0.ffn.wi"], bits)
-    hh = jax.nn.silu(gate) * up                   # in the activation dtype
-    return x + qgemm(hh, codes["0.ffn.wo"], m["0.ffn.wo"], bits)
-
-
 @functools.partial(jax.jit, static_argnames=("eps",))
 def head_logits(x, rows, head_w, *, eps):
     """Float32 logits of ``rows`` of the final hidden states."""
@@ -219,13 +194,15 @@ def head_logits(x, rows, head_w, *, eps):
 class Reference:
     """Logits of the plain model for given token sequences.
 
-    ``dims``: n_units, n_heads, n_kv_heads, hd, rope_fraction,
-    rope_theta, eps.  ``meta``: {linear name: exponents} from
-    ``chipbench.weights.linear_meta``.  ``draw_unit(u)`` gives unit u's
-    INT8 codes, ``embed``/``head`` the float tables."""
+    ``layer(x, codes, *, dims, meta, bits)`` is the family's jitted
+    layer (``dims`` and ``meta`` as sorted tuples).  ``dims``: n_units,
+    eps and whatever the layer reads.  ``meta``: {linear name:
+    exponents} from ``chipbench.weights.linear_meta``.  ``draw_unit(u)``
+    gives unit u's INT8 codes, ``embed``/``head`` the float tables."""
 
-    def __init__(self, dims: dict, meta: dict, draw_unit, embed, head,
-                 t_pad: int, bits: int = 8):
+    def __init__(self, layer, dims: dict, meta: dict, draw_unit, embed,
+                 head, t_pad: int, bits: int = 8):
+        self.layer = layer
         self.dims = tuple(sorted(dims.items()))
         self.meta = tuple(sorted((k, tuple(sorted(v.items())))
                                  for k, v in meta.items()))
@@ -248,7 +225,8 @@ class Reference:
             xs.append(jnp.take(self.embed, jnp.asarray(t), axis=0))
         for u in range(self.n_units):
             codes = self.draw_unit(u)
-            xs = [layer(x, codes, dims=self.dims, meta=self.meta, bits=bits)
+            xs = [self.layer(x, codes, dims=self.dims, meta=self.meta,
+                             bits=bits)
                   for x in xs]
             del codes
         out = []

@@ -3,8 +3,9 @@
     PYTHONPATH=src python -m pytest chipbench/tests -q
 
 They cover the yardstick (traffic, op and byte counts, the trace
-reduction on a recorded trace, the peak table), discovery by name, the
-entry point's refusal without a TPU, and whole runs at a test size with
+reduction on a recorded trace, the peak table), discovery by name (a
+model family given as a new file among it), the entry point's refusal
+without a TPU, and whole runs at a test size with
 the oracle backend: a sound run passes its check, the control (the
 reference at INT4 in the program's place) fails it, and one slot's
 tokens altered where the engine produces them, or a decode that drops
@@ -32,6 +33,12 @@ from chipbench import (counts, faults, harness, stats, trace,  # noqa: E402
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 MIXES = sorted(p.stem for p in (ROOT / "chipbench" / "traffic").glob("*.json"))
+V5E = "TPU v5 lite"
+
+
+@pytest.fixture(scope="module")
+def dense():
+    return harness.Bench(ROOT).family({})
 
 
 # ---------------------------------------------------------------------------
@@ -94,6 +101,30 @@ def test_every_named_file_exists():
         assert (ROOT / c["file"]).is_file()
 
 
+# A family given as a new file: the dense one, each of its functions
+# recording its name when it is called.
+TOY_FAMILY = '''from pathlib import Path
+
+from chipbench.families import dense
+
+LOG = Path(__file__).with_name("toy.calls")
+
+
+def _logged(fn):
+    def wrap(*args, **kw):
+        with open(LOG, "a") as f:
+            f.write(fn.__name__ + "\\n")
+        return fn(*args, **kw)
+    return wrap
+
+
+(dims, check, deployed_shapes, mean_abs_in, float_init, reference,
+ call_costs) = map(_logged, (
+    dense.dims, dense.check, dense.deployed_shapes, dense.mean_abs_in,
+    dense.float_init, dense.reference, dense.call_costs))
+'''
+
+
 def test_new_files_are_found_without_code_change(tmp_path):
     shutil.copytree(ROOT / "chipbench", tmp_path / "chipbench",
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -104,8 +135,15 @@ def test_new_files_are_found_without_code_change(tmp_path):
         "moves": "output_tok_s", "workloads": ["cfg_new.mix_new"]})
     spec["workloads"].append({"name": "cfg_new.mix_new", "config": "cfg_new",
                               "traffic": "mix_new", "chips": 1, "why": "t"})
+    spec["workloads"].append({"name": "toy.small", "config": "toy",
+                              "traffic": "small", "chips": 1, "why": "t"})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
     d = tmp_path / "chipbench"
+    (d / "families" / "toy.py").write_text(TOY_FAMILY)
+    (d / "configs" / "toy.json").write_text(
+        json.dumps(dict(TINY_CONF, family="toy")))
+    (d / "traffic" / "small.json").write_text(json.dumps(TINY_MIX))
+    (d / "limits" / "toy.small.json").write_text(json.dumps(TINY_LIMITS))
     (d / "metrics" / "new_metric.py").write_text(
         "def read(ctx):\n    return 42.0\n")
     (d / "configs" / "cfg_new.json").write_text('{"chips": 1}')
@@ -120,51 +158,101 @@ def test_new_files_are_found_without_code_change(tmp_path):
     names = [m["name"] for m in b.metrics("cfg_new.mix_new", True)]
     assert names == ["new_metric"]
     assert b.reader("new_metric")({}) == 42.0
+    toy = b.family(b.config("toy"))
+    assert Path(toy.__file__) == d / "families" / "toy.py"
+    out = harness.run(tmp_path, "toy.small", 2**31 + 81, 2.0, False,
+                      t_start=time.perf_counter(), backend="oracle",
+                      require_tpu=False)
+    assert out["correct"] is True
+    log = d / "families" / "toy.calls"
+    assert set(log.read_text().split()) == {
+        "dims", "check", "deployed_shapes", "mean_abs_in", "float_init",
+        "reference"}
+    # The costs are taken only where the chip's peaks are known (a traced
+    # run on the TPU): here at the v5e's, on a decode dispatch.
+    rec = {"kind": "decode", "rows": 4, "steps": [[20, 31], [21]]}
+    dims, peak = toy.dims(TINY_CONF), harness.peaks_for(V5E)
+    assert toy.call_costs(dims, rec, peak) == harness.Bench(ROOT).family(
+        {}).call_costs(dims, rec, peak)
+    assert log.read_text().split()[-1] == "call_costs"
+
+
+def test_unknown_family_is_an_error():
+    with pytest.raises(KeyError, match=r"'sparse'.*known: \['dense'\]"):
+        harness.Bench(ROOT).family({"family": "sparse"})
 
 
 # ---------------------------------------------------------------------------
 # Ops and bytes, against hand counts
 # ---------------------------------------------------------------------------
 
-def _dims(name):
-    return harness.model_dims(json.loads(
-        (ROOT / "chipbench" / "configs" / f"{name}.json").read_text()))
-
-
-def test_counts_deepseek_by_hand():
-    d = _dims("deepseek-7b")
+def test_counts_deepseek_by_hand(dense):
+    d = dense.dims(harness.Bench(ROOT).config("deepseek-7b"))
     assert counts.gemm_ops(16, 4096, 4096) == 536_870_912
     # 4096*11008 weights + 16*4096 codes + 4*16*11008 out + 4*4*11008 exps
     assert counts.gemm_bytes(16, 4096, 11008, 4) == 46_034_944
     # per layer 4*4096^2 + 3*4096*11008 = 202,375,168; 30 layers + head
-    assert counts.gemm_params(d) == 30 * 202_375_168 + 4096 * 102400
+    assert dense.gemm_params(d) == 30 * 202_375_168 + 4096 * 102400
     # one decode row at ctx 300: 2*GEMM params + 30 * 4*300*32*128
-    assert counts.token_ops(d, 300) == (2 * (30 * 202_375_168
-                                             + 4096 * 102400)
-                                        + 30 * 4 * 300 * 32 * 128)
+    assert dense.token_ops(d, 300) == (2 * (30 * 202_375_168
+                                            + 4096 * 102400)
+                                       + 30 * 4 * 300 * 32 * 128)
     # 2 slots at 100 and 200 live tokens: K and V codes of 300 positions
     assert counts.attn_bytes(d, 300, 2) == 2 * 300 * 32 * 128 + 8 * 2 * 4096
 
 
-def test_counts_chatglm_by_hand():
-    d = _dims("chatglm3-6b")
+def test_counts_chatglm_by_hand(dense):
+    d = dense.dims(harness.Bench(ROOT).config("chatglm3-6b"))
     assert counts.attn_bytes(d, 100, 1) == 83_968
-    assert counts.token_ops(d, 100) == 11_999_641_600
-    peak = harness.peaks_for("TPU v5 lite")
-    c = counts.call_costs(d, {"kind": "prefill", "start": 32, "chunk": 2},
-                          peak)
+    assert dense.token_ops(d, 100) == 11_999_641_600
+    peak = harness.peaks_for(V5E)
+    c = dense.call_costs(d, {"kind": "prefill", "start": 32, "chunk": 2},
+                         peak)
     assert c["tokens"] == 2
-    assert c["model_ops"] == counts.token_ops(d, 33) + counts.token_ops(d, 34)
+    assert c["model_ops"] == dense.token_ops(d, 33) + dense.token_ops(d, 34)
     # prefill at M=2 is bandwidth-bound in every GEMM
     want = 28 * sum(counts.gemm_bytes(2, k, n, 4)
-                    for k, n in counts.linear_shapes(d)) / 819e9
+                    for k, n in dense.linear_shapes(d)) / 819e9
     assert c["gemm_s"] == pytest.approx(want, rel=1e-12)
-    dec = counts.call_costs(d, {"kind": "decode", "rows": 16,
-                                "steps": [[10, 20], [11]]}, peak)
+    dec = dense.call_costs(d, {"kind": "decode", "rows": 16,
+                               "steps": [[10, 20], [11]]}, peak)
     assert dec["tokens"] == 3
     assert dec["attn_s"] == pytest.approx(
         28 * (counts.attn_bytes(d, 30, 2) + counts.attn_bytes(d, 11, 1))
         / 819e9, rel=1e-12)
+
+
+COST_READERS = {"apsq_gemm_roofline": "gemm_s", "kv_attn_roofline": "attn_s",
+                "step_mfu": "model_ops"}
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
+def test_cost_readers_take_the_family_costs(dense, config):
+    """Dispatches and a reduced trace recorded on the chip, beside the
+    values the cost readers printed there when they called the dense
+    costs themselves: fed the family's costs through ``ctx``, the readers
+    give the same values, and those follow from ``call_costs`` called
+    directly on the records."""
+    b = harness.Bench(ROOT)
+    (path,) = (ROOT / "chipbench" / "testdata").glob(
+        f"dispatches.{config}.*.json")
+    rec = json.loads(path.read_text())
+    dims, peak = dense.dims(b.config(config)), harness.peaks_for(V5E)
+    tr = rec["trace"]
+    costs = [dense.call_costs(dims, c, peak) for c in rec["calls"]]
+    assert len(costs) > 20
+    ctx = {"trace": tr, "calls": rec["calls"], "dims": dims, "peak": peak,
+           "call_costs": costs}
+    for metric, part in COST_READERS.items():
+        got = b.reader(metric)(ctx)
+        assert got == rec["per_layer"][metric]
+        total = sum(c[part] for c in costs)
+        if metric == "step_mfu":
+            assert got == 100.0 * total / (tr["window_s"] * peak["int8_ops"])
+        else:
+            k = "apsq_gemm" if part == "gemm_s" else "kv_attn"
+            assert got == 100.0 * total / (tr["kernels"][k]
+                                           + tr["staging"].get(k, 0.0))
 
 
 # ---------------------------------------------------------------------------
@@ -225,7 +313,7 @@ def test_percentile_nearest_rank():
 # ---------------------------------------------------------------------------
 
 def test_peak_table_has_v5e_and_its_source():
-    p = harness.peaks_for("TPU v5 lite")
+    p = harness.peaks_for(V5E)
     assert (p["bf16_flops"], p["int8_ops"], p["hbm_bytes_s"]) == (
         197e12, 393e12, 819e9)
     assert "TPU v5e" in harness.load_json(
@@ -298,7 +386,9 @@ TINY_LIMITS = {"compare": {"worst_request_gap": 0.2, "gap_share": 0.15},
 def tiny_root(tmp_path_factory):
     root = tmp_path_factory.mktemp("tiny")
     d = root / "chipbench"
-    shutil.copytree(ROOT / "chipbench" / "metrics", d / "metrics")
+    for sub in ("metrics", "families"):
+        shutil.copytree(ROOT / "chipbench" / sub, d / sub,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     for sub, name, obj in (("configs", "tiny", TINY_CONF),
                            ("traffic", "small", TINY_MIX),
                            ("limits", "tiny.small", TINY_LIMITS)):
@@ -345,6 +435,81 @@ def test_broken_timed_path_is_not_correct(tiny_root, fault, seed):
     assert any(v["value"] > v["limit"] for v in out["check"].values())
 
 
+# A configuration of a family whose deployed tree is not dense: the smoke
+# OLMoE (8 experts, top 2) with its family file given as new files only.
+# Its capacity never binds, as its family requires.
+MOE_CONF = dict(
+    TINY_CONF, arch="olmoe-1b-7b", family="moe",
+    model_cfg={"capacity_factor": 4.0},
+    model=dict(TINY_CONF["model"], n_kv_heads=4, d_ff=64, rope_fraction=1.0,
+               n_experts=8, top_k=2, capacity_factor=4.0))
+# At this size (CPU, oracle backend), worst_request_gap / gap_share:
+# sound runs 0.052-0.184 / 0-0.0031 over 11 seeds, the control (INT4
+# codes) 2.75-2.77 / 0.81-0.83 over 2: routing flips on near ties lift
+# the sound readings above the dense cell's, so the first limit is 0.5.
+MOE_LIMITS = {"compare": {"worst_request_gap": 0.5, "gap_share": 0.15},
+              "control_bits": 4}
+
+
+def test_expert_family_is_new_files_only(tmp_path):
+    """A sparse-expert family (``chipbench/testdata/families/moe.py``)
+    and its cell added as new files: its deployed tree is the program's
+    own export, its weights are drawn, the engine serves them, its
+    reference passes the check, and its costs count every expert."""
+    import jax
+
+    from chipbench import weights
+    from repro.models.model import init_lm
+    from repro.quant.export import export_quantized
+
+    shutil.copytree(ROOT / "chipbench", tmp_path / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    d = tmp_path / "chipbench"
+    shutil.copy(d / "testdata" / "families" / "moe.py",
+                d / "families" / "moe.py")
+    for sub, name, obj in (("configs", "moe", MOE_CONF),
+                           ("traffic", "small", TINY_MIX),
+                           ("limits", "moe.small", MOE_LIMITS)):
+        (d / sub / f"{name}.json").write_text(json.dumps(obj))
+    spec = json.loads(json.dumps(BENCH))
+    spec["configs"].append({"name": "moe", "source": "t",
+                            "file": "chipbench/configs/moe.json",
+                            "reduced": [], "why": "t"})
+    spec["workloads"].append({"name": "moe.small", "config": "moe",
+                              "traffic": "small", "chips": 1, "why": "t"})
+    for m in spec["end_to_end"]:
+        m.pop("workloads", None)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    b = harness.Bench(tmp_path)
+    fam = b.family(b.config("moe"))
+    cfg = weights.model_config(MOE_CONF)
+    abstract = fam.deployed_shapes(cfg)
+    real, _ = export_quantized(init_lm(jax.random.PRNGKey(0), cfg))
+
+    def shapes(t):
+        return jax.tree.map(lambda x: (x.shape, x.dtype), t)
+
+    assert shapes(abstract) == shapes(real)
+    assert abstract["units"]["0"]["ffn"]["qp_wo"].w_codes.shape == (
+        2, 8, 64, 64)
+    out = harness.run(tmp_path, "moe.small", 2**31 + 91, 2.0, False,
+                      t_start=time.perf_counter(), backend="oracle",
+                      require_tpu=False)
+    assert out["correct"] is True, out["check"]
+    # One decode step of 4 rows: each expert's three GEMMs run over its
+    # capacity ceil(4 * 2 / 8 * 4.0) = 4 rows, bandwidth-bound at this size.
+    dims, peak = fam.dims(MOE_CONF), harness.peaks_for(V5E)
+    c = fam.call_costs(dims, {"kind": "decode", "rows": 4, "steps": [[9]]},
+                       peak)
+    attn = sum(counts.gemm_bytes(4, k, n, 4)
+               for k, n in [(64, 64)] * 4)
+    experts = 8 * (2 * counts.gemm_bytes(4, 64, 64, 4)
+                   + counts.gemm_bytes(4, 64, 64, 4))
+    assert c["gemm_s"] == pytest.approx(2 * (attn + experts) / 819e9,
+                                        rel=1e-12)
+    assert c["tokens"] == 1
+
+
 def test_dispatch_records_match_the_engine():
     """The dispatches told from counters and deliveries are the ones the
     engine made (read here by wrapping its private programs)."""
@@ -352,7 +517,9 @@ def test_dispatch_records_match_the_engine():
 
     conf, mix = TINY_CONF, TINY_MIX
     cfg = weights.model_config(conf)
-    params = weights.make_params(2**31 + 80, cfg)
+    fam = harness.Bench(ROOT).family(conf)
+    params = weights.make_params(2**31 + 80, fam.deployed_shapes(cfg),
+                                 fam.mean_abs_in, fam.float_init)
     eng = harness._engine(params, cfg, conf["engine"], "oracle")
     harness.warm(eng, conf["engine"])
     true = []

@@ -2,21 +2,24 @@
 
 The served tree is what ``repro.quant.export`` hands the engine: every
 quantized linear becomes a ``DeployedQuantState`` (INT8 weight codes,
-activation / weight / partial-sum shift exponents); the embedding table,
-the norms and the untied LM head stay in the model's float dtype.  Its
-structure and shapes come from ``jax.eval_shape`` of the program's own
-``init_lm`` and per-linear export, so no float model is ever held.
+activation / weight / partial-sum shift exponents; stacked over experts
+where the family has them); the embedding table, the norms and the
+untied LM head stay in the model's float dtype.  Its structure and
+shapes are the family's ``deployed_shapes(cfg)`` (``chipbench/families``:
+``jax.eval_shape`` of the program's own ``init_lm`` and export), so no
+float model is ever held.
 
 Every value is a hash (murmur3's finalizer) of its index within its
 array, keyed per leaf from the 64-bit seed and, for scan-stacked units,
 per unit: elementwise, so the whole tree is one jitted call that writes
 only its outputs, and the reference draws any one unit alone and gets
-the same codes.  Weight codes are symmetric on [-15, 15], the embedding
-and LM head uniform with the fan-in scale 1/sqrt(d); the exponents are
-set as a calibrated deployment's would be (the rule of
+the same values.  Weight codes are symmetric on [-15, 15]; the exponents
+are set as a calibrated deployment's would be (the rule of
 ``repro.core.init_alpha_from``, 2 * mean|x| / sqrt(127), snapped down to
-a power of two), from the activation magnitude each projection sees, so
-that activations and partial sums stay in INT8 range.
+a power of two), from the activation magnitude each linear sees, so
+that activations and partial sums stay in INT8 range.  What a linear's
+input magnitude is, and how each float leaf is drawn, is the family's
+word: its ``mean_abs_in(names)`` and ``float_init(names, shape)``.
 """
 from __future__ import annotations
 
@@ -32,10 +35,8 @@ import numpy as np
 # layer, drive the residual stream along one direction).
 CODE_SHIFT = 3
 CODE_STD = math.sqrt(2 * sum(k * k for k in range(1, 16)) / 32.0)
-# Mean |x| of the float activation entering each projection, by role:
-# RMS-normed inputs and attention outputs have unit scale (mean |N(0,1)|);
-# the SwiGLU product silu(g) * i of two unit normals has mean |.| ~ 0.42.
-MEAN_ABS_IN = {"mlp_out": 0.42}
+# Mean |x| of a unit-scale activation (mean |N(0,1)|), such as an
+# RMS-normed input.
 MEAN_ABS_UNIT = math.sqrt(2.0 / math.pi)
 QMAX = 127.0
 
@@ -58,57 +59,19 @@ def model_config(conf: dict):
     return cfg.with_quant(quant).scaled(dtype=conf.get("dtype", cfg.dtype))
 
 
-def _is_linear(t) -> bool:
-    from repro.core import QuantState
-    return isinstance(t, dict) and "w" in t and isinstance(t.get("qp"),
-                                                           QuantState)
-
-
-def deployed_shapes(cfg):
-    """Abstract deployed tree: init's tree with each quantized linear
-    replaced by the program's own export of it (shapes only)."""
-    from repro.models.model import init_lm
-    from repro.quant.export import _export_one
-
-    abstract = jax.eval_shape(lambda k: init_lm(k, cfg),
-                              jax.random.PRNGKey(0))
-
-    def walk(t):
-        if _is_linear(t):
-            w, qp = t["w"], t["qp"]
-            fn = _export_one
-            if qp.ax.ndim == 1:                       # scan-stacked units
-                fn = jax.vmap(_export_one)
-            dq, _ = jax.eval_shape(fn, w, qp)
-            return {"qp": dq}
-        if isinstance(t, dict):
-            return {k: walk(v) for k, v in t.items()}
-        return t
-
-    return walk(abstract)
-
-
-def _role(path: tuple) -> str:
-    names = [str(getattr(p, "key", getattr(p, "name", ""))) for p in path]
-    if "ffn" in names and "wo" in names:
-        return "mlp_out"
-    return "unit"
-
-
-def _ax_exp(role: str) -> int:
-    m = MEAN_ABS_IN.get(role, MEAN_ABS_UNIT)
+def _ax_exp(m: float) -> int:
     return math.floor(math.log2(2.0 * m / math.sqrt(QMAX)))
 
 
-def linear_exponents(role: str, k: int, n_p: int):
-    """(ax_exp, aw_exp, [psum exps]) of one linear with reduction dim K.
+def linear_exponents(m: float, k: int, n_p: int):
+    """(ax_exp, aw_exp, [psum exps]) of one linear with reduction dim K
+    whose float input has mean |x| ``m``.
 
     aw: codes of std CODE_STD times 2^aw give the fan-in scale 1/sqrt(K).
     PSUM tile i holds the running sum of (i+1) K/n_p products; its scale
     is 2 * mean|running| / sqrt(127), in product-scale units."""
-    ax = _ax_exp(role)
+    ax = _ax_exp(m)
     aw = round(math.log2(1.0 / (CODE_STD * math.sqrt(k))))
-    m = MEAN_ABS_IN.get(role, MEAN_ABS_UNIT)
     x_code_std = (m / MEAN_ABS_UNIT) * 2.0 ** (-ax)
     kt = -(-k // n_p)
     ps = []
@@ -160,25 +123,56 @@ def _uniform(key, shape, std, dtype):
     return ((u - 0.5) * (math.sqrt(12.0) * std)).astype(dtype)
 
 
-def _leaf_value(key, path, leaf, stacked: bool, n_units: int):
-    """Value of one abstract leaf (codes, exponents or a float table)."""
-    names = [str(getattr(p, "key", getattr(p, "name", ""))) for p in path]
-    field = names[-1]
-    dt = leaf.dtype
-    if field == "w_codes":
-        if stacked:
-            return jax.vmap(lambda u: _codes(_unit_key(key, u),
-                                             leaf.shape[1:]))(
-                jnp.arange(n_units, dtype=jnp.uint32))
-        return _codes(key, leaf.shape)
-    if field in ("ax_exp", "aw_exp", "psum_exps"):
-        return None                                   # filled by the caller
-    if field == "scale":                              # norm gains
-        return jnp.ones(leaf.shape, dt)
-    if field in ("table", "w"):                       # embedding / LM head
-        fan = leaf.shape[-1] if field == "table" else leaf.shape[0]
-        return _uniform(key, leaf.shape, 1.0 / math.sqrt(fan), dt)
-    raise ValueError(f"no rule for leaf {jax.tree_util.keystr(path)}")
+def _names(path) -> list:
+    return [str(getattr(p, "key", getattr(p, "name", ""))) for p in path]
+
+
+def _stacked(path) -> bool:
+    """Leaves under ``units`` carry the scan's leading unit axis."""
+    return bool(path) and getattr(path[0], "key", None) == "units"
+
+
+def _float(key, shape, init, dtype):
+    """A float leaf from its family's ``(mean, std)``: the constant mean
+    where std is 0, else uniform values of that std about the mean."""
+    mean, std = init
+    if not std:
+        return jnp.full(shape, mean, dtype)
+    return (mean + _uniform(key, shape, std, jnp.float32)).astype(dtype)
+
+
+def _per_unit(fn, key, n_units: int):
+    """``fn(unit key)`` for every unit, stacked on a leading axis."""
+    return jax.vmap(lambda u: fn(_unit_key(key, u)))(
+        jnp.arange(n_units, dtype=jnp.uint32))
+
+
+def _float_leaf(key, path, leaf, float_init):
+    shape = leaf.shape[1:] if _stacked(path) else leaf.shape
+    init = float_init(_names(path), shape)
+    fn = lambda k: _float(k, shape, init, leaf.dtype)  # noqa: E731
+    return _per_unit(fn, key, leaf.shape[0]) if _stacked(path) else fn(key)
+
+
+def _linear(key, path, t, mean_abs: float):
+    """A ``DeployedQuantState`` with codes drawn from ``key`` (its
+    ``w_codes`` leaf's) and the exponents of an input of mean |x|
+    ``mean_abs``.  Any leading axes (units, experts) share them."""
+    from repro.core import DeployedQuantState
+
+    shape = t.w_codes.shape
+    if _stacked(path):
+        codes = _per_unit(lambda k: _codes(k, shape[1:]), key, shape[0])
+    else:
+        codes = _codes(key, shape)
+    ax, aw, ps = linear_exponents(mean_abs, shape[-2], t.psum_exps.shape[-2])
+    ps_e = jnp.broadcast_to(jnp.asarray(ps, jnp.int32)[:, None],
+                            t.psum_exps.shape[-2:])
+    return DeployedQuantState(
+        w_codes=codes, ax_exp=jnp.full(t.ax_exp.shape, ax, jnp.int32),
+        aw_exp=jnp.full(t.aw_exp.shape, aw, jnp.int32),
+        psum_exps=jnp.broadcast_to(ps_e, t.psum_exps.shape),
+        spec=t.spec, name=t.name, out_dims=t.out_dims)
 
 
 def leaf_paths(abstract):
@@ -186,106 +180,109 @@ def leaf_paths(abstract):
     return jax.tree_util.tree_leaves_with_path(abstract)
 
 
-def _build(key, abstract, n_units: int):
+def _leaf_index(abstract) -> dict:
+    return {jax.tree_util.keystr(p): i
+            for i, (p, _) in enumerate(leaf_paths(abstract))}
+
+
+def _codes_path(path):
+    return path + (jax.tree_util.GetAttrKey("w_codes"),)
+
+
+def _build(key, abstract, mean_abs_in, float_init):
     from repro.core import DeployedQuantState
 
-    flat = leaf_paths(abstract)
-    index = {jax.tree_util.keystr(p): i for i, (p, _) in enumerate(flat)}
+    index = _leaf_index(abstract)
 
     def walk(t, prefix):
         if isinstance(t, DeployedQuantState):
-            kpath = lambda f: prefix + (jax.tree_util.GetAttrKey(f),)  # noqa
-            wpath = kpath("w_codes")
-            i = index[jax.tree_util.keystr(wpath)]
-            stacked = t.ax_exp.ndim == 1
-            codes = _leaf_value(key[i], wpath, t.w_codes, stacked, n_units)
-            k = t.w_codes.shape[-2]
-            n_p = t.psum_exps.shape[-2]
-            ax, aw, ps = linear_exponents(_role(prefix), k, n_p)
-            ax_e = jnp.full(t.ax_exp.shape, ax, jnp.int32)
-            aw_e = jnp.full(t.aw_exp.shape, aw, jnp.int32)
-            ps_e = jnp.broadcast_to(
-                jnp.asarray(ps, jnp.int32)[:, None],
-                t.psum_exps.shape[-2:])
-            ps_e = jnp.broadcast_to(ps_e, t.psum_exps.shape)
-            return DeployedQuantState(
-                w_codes=codes, ax_exp=ax_e, aw_exp=aw_e, psum_exps=ps_e,
-                spec=t.spec, name=t.name, out_dims=t.out_dims)
+            i = index[jax.tree_util.keystr(_codes_path(prefix))]
+            return _linear(key[i], prefix, t, mean_abs_in(_names(prefix)))
         if isinstance(t, dict):
             return {k: walk(v, prefix + (jax.tree_util.DictKey(k),))
                     for k, v in t.items()}
         i = index[jax.tree_util.keystr(prefix)]
-        return _leaf_value(key[i], prefix, t, False, n_units)
+        return _float_leaf(key[i], prefix, t, float_init)
 
     return walk(abstract, ())
 
 
-def make_params(seed: int, cfg, abstract=None):
-    """The whole deployed tree on the default device, in one jitted call."""
-    if abstract is None:
-        abstract = deployed_shapes(cfg)
-    return jax.jit(lambda k: _build(k, abstract, cfg.n_units))(
+def make_params(seed: int, abstract, mean_abs_in, float_init):
+    """The whole deployed tree on the default device, in one jitted call.
+    ``abstract``, ``mean_abs_in`` and ``float_init`` are the family's
+    ``deployed_shapes(cfg)`` and rules."""
+    return jax.jit(lambda k: _build(k, abstract, mean_abs_in, float_init))(
         seed_key(seed))
 
 
-def _unit_linears(abstract):
-    """{dotted name: (abstract DeployedQuantState, path)} of the scan
-    units' linears, e.g. ``0.mix.wq``."""
-    from repro.core import DeployedQuantState
-
+def unit_leaves(abstract) -> dict:
+    """{dotted path below ``units``: (abstract, path)} of every linear
+    (a whole ``DeployedQuantState``, e.g. ``0.mix.wq.qp``) and every
+    float leaf (``0.ln1.scale``) of the scan units."""
     out = {}
 
-    def walk(t, prefix, names):
-        if isinstance(t, DeployedQuantState):
-            out[".".join(names[:-1])] = (t, prefix)
-        elif isinstance(t, dict):
+    def walk(t, prefix):
+        if isinstance(t, dict):
             for k, v in t.items():
-                walk(v, prefix + (jax.tree_util.DictKey(k),), names + [k])
+                walk(v, prefix + (jax.tree_util.DictKey(k),))
+        else:
+            out[".".join(_names(prefix[1:]))] = (t, prefix)
 
-    walk(abstract["units"], (jax.tree_util.DictKey("units"),), [])
+    walk(abstract["units"], (jax.tree_util.DictKey("units"),))
     return out
 
 
-def linear_meta(abstract) -> dict:
+def linear_meta(abstract, mean_abs_in) -> dict:
     """{name: dict(ax, aw, ps, gs, mode)} of every unit linear: the
     static exponents ``make_params`` gives it."""
+    from repro.core import DeployedQuantState
+
     meta = {}
-    for name, (t, prefix) in _unit_linears(abstract).items():
-        ax, aw, ps = linear_exponents(_role(prefix), t.w_codes.shape[-2],
-                                      t.psum_exps.shape[-2])
-        meta[name] = {"ax": ax, "aw": aw, "ps": tuple(ps),
-                      "gs": t.spec.psum.gs, "mode": t.spec.psum.mode}
+    for name, (t, path) in unit_leaves(abstract).items():
+        if isinstance(t, DeployedQuantState):
+            ax, aw, ps = linear_exponents(mean_abs_in(_names(path)),
+                                          t.w_codes.shape[-2],
+                                          t.psum_exps.shape[-2])
+            meta[name] = {"ax": ax, "aw": aw, "ps": tuple(ps),
+                          "gs": t.spec.psum.gs, "mode": t.spec.psum.mode}
     return meta
 
 
-def unit_codes_fn(abstract):
-    """jitted ``(key, unit) -> {name: int8 codes}``: the codes
-    ``make_params`` gives that unit's linears, drawn alone."""
-    index = {jax.tree_util.keystr(p): i
-             for i, (p, _) in enumerate(leaf_paths(abstract))}
-    lins = _unit_linears(abstract)
+def unit_fn(abstract, float_init):
+    """jitted ``(key, unit) -> {name: value}``: what ``make_params``
+    gives that unit, drawn alone: each linear's INT8 codes and each float
+    leaf, under ``unit_leaves``' names."""
+    from repro.core import DeployedQuantState
+
+    index = _leaf_index(abstract)
+    leaves = unit_leaves(abstract)
 
     @jax.jit
     def build(key, u):
         out = {}
-        for name, (t, prefix) in lins.items():
-            wpath = prefix + (jax.tree_util.GetAttrKey("w_codes"),)
-            i = index[jax.tree_util.keystr(wpath)]
-            out[name] = _codes(_unit_key(key[i], u), t.w_codes.shape[1:])
+        for name, (t, path) in leaves.items():
+            if isinstance(t, DeployedQuantState):
+                i = index[jax.tree_util.keystr(_codes_path(path))]
+                out[name] = _codes(_unit_key(key[i], u), t.w_codes.shape[1:])
+            else:
+                i = index[jax.tree_util.keystr(path)]
+                shape = t.shape[1:]
+                out[name] = _float(_unit_key(key[i], u), shape,
+                                   float_init(_names(path), shape), t.dtype)
         return out
 
     return build
 
 
-def float_leaf(key, abstract, *names):
+def float_leaf(key, abstract, float_init, *names):
     """A float leaf outside the units (``embed/table``, ``head/w``,
     ``final_norm/scale``), with the value ``make_params`` gives it."""
-    flat = leaf_paths(abstract)
     want = tuple(jax.tree_util.DictKey(n) for n in names)
-    for i, (p, leaf) in enumerate(flat):
+    for i, (p, leaf) in enumerate(leaf_paths(abstract)):
         if tuple(p) == want:
-            return jax.jit(lambda k: _leaf_value(k[i], p, leaf, False, 0))(
-                key)
+            init = float_init(list(names), leaf.shape)
+            return jax.jit(lambda k: _float(k[i], leaf.shape, init,
+                                            leaf.dtype))(key)
     raise KeyError(names)
 
 
